@@ -132,12 +132,12 @@ from jax.sharding import PartitionSpec as P
 import sys
 sys.path.insert(0, "src")
 from repro.core import collectives as coll
-from repro.launch import compat
-mesh = compat.make_mesh((4, 4), ("data", "model"))
+mesh = jax.make_mesh((4, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 x = jax.ShapeDtypeStruct((1 << 20,), jnp.float32)  # 4 MiB
 for algo in ("psum", "ring", "bidir", "torus", "hamiltonian"):
     lo = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             lambda v, a=algo: coll.allreduce(v, a, ("data", "model"), (4, 4)),
             mesh=mesh, check_vma=False, in_specs=P(), out_specs=P(),
         )
@@ -147,7 +147,7 @@ for algo in ("psum", "ring", "bidir", "torus", "hamiltonian"):
     n_ar = len(re.findall(r"all-reduce(?!-)", txt))
     print(f"MEASURE,{algo},permutes={n_perm},allreduces={n_ar}")
 """
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")  # fake host devices, never a chip
     proc = subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True,
         env=env, timeout=600,

@@ -34,7 +34,6 @@ from jax import lax
 from repro.core import commodel
 from repro.core import hamiltonian as ham
 
-from repro.launch import compat
 
 AxisName = str | tuple[str, ...]
 
@@ -105,7 +104,7 @@ def _dyn_set(out: jax.Array, i: jax.Array, val: jax.Array) -> jax.Array:
 def _ring_allreduce_1d(
     x: jax.Array, axis: str, reverse: bool = False
 ) -> jax.Array:
-    p = compat.axis_size(axis)
+    p = lax.axis_size(axis)
     rank = lax.axis_index(axis)
     if reverse:
         rank = p - 1 - rank
@@ -130,7 +129,7 @@ def ring_allreduce(x: jax.Array, axis: str) -> jax.Array:
 
 def ring_reduce_scatter(x: jax.Array, axis: str) -> jax.Array:
     """Reduce-scatter returning this device's chunk (index = axis_index)."""
-    p = compat.axis_size(axis)
+    p = lax.axis_size(axis)
     rank = lax.axis_index(axis)
     perm = _ring_perm(p)
     chunks, _ = _chunked(x, p)
@@ -141,7 +140,7 @@ def ring_reduce_scatter(x: jax.Array, axis: str) -> jax.Array:
 
 def ring_all_gather(x: jax.Array, axis: str) -> jax.Array:
     """All-gather of per-device chunks (chunk index = axis_index)."""
-    p = compat.axis_size(axis)
+    p = lax.axis_size(axis)
     rank = lax.axis_index(axis)
     perm = _ring_perm(p)
     return _ring_all_gather(x, jnp.mod(rank - 1, p), p, perm, axis)
@@ -174,7 +173,12 @@ def hamiltonian_allreduce(
     """
     r, c = mesh_shape
     p = r * c
-    red, green = ham.dual_cycles(r, c)
+    try:
+        red, green = ham.dual_cycles(r, c)
+    except ValueError:
+        # no edge-disjoint pair (e.g. 2x2): as netsim does, run every quarter
+        # over one Hamiltonian cycle, two in each direction
+        red = green = ham.single_cycle(r, c)
 
     def mk(cycle):
         # device (i,j) -> rank in cycle; perm pairs over linearized (i*c+j)
@@ -233,7 +237,7 @@ def torus_allreduce(
     """
 
     def one(inp: jax.Array, ax0: str, ax1: str) -> jax.Array:
-        p0 = compat.axis_size(ax0)
+        p0 = lax.axis_size(ax0)
         rank0 = lax.axis_index(ax0)
         perm0 = _ring_perm(p0)
         chunks, pad0 = _chunked(inp, p0)
@@ -321,7 +325,7 @@ def allreduce_tree(
     if mean:
         n = 1
         for ax in axes:
-            n *= compat.axis_size(ax)
+            n *= lax.axis_size(ax)
         total = total / n
     out = []
     off = 0

@@ -15,8 +15,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-
-from repro.launch import compat
+from jax import lax
 
 
 class CompressionState(NamedTuple):
@@ -62,7 +61,7 @@ def sparse_allreduce(
     n = grad.size
     dense = jnp.zeros((n,), grad.dtype)
     dense = dense.at[all_idx.reshape(-1)].add(all_vals.reshape(-1))
-    d = compat.axis_size(axis_name)
+    d = lax.axis_size(axis_name)
     return (dense / d).reshape(grad.shape), new_state
 
 

@@ -5,6 +5,11 @@ Design (TPU-native, not a CUDA port — see DESIGN.md hardware adaptation):
 * Grid = (batch × q-heads, Sq/BQ, Sk/BK).  The last grid dimension iterates
   sequentially on TPU, so the online-softmax running state (m, l, acc) lives
   in VMEM scratch and persists across KV blocks of the same (head, q-block).
+* The kernel works on a heads-major (B, H, S, D) layout, so every block's
+  last two dimensions are (BQ or BK, D): a sublane multiple of 8 by the full
+  head width, the tiling the TPU compiler requires.  The (B, S, H, D) layout
+  of the callers would put a block of 1 head in the sublane dimension, which
+  the compiler rejects.
 * BlockSpecs stream one (BQ, D) query tile and one (BK, D) key/value tile
   into VMEM per step; the (BQ, BK) score tile hits the MXU via jnp.dot with
   fp32 accumulation.  BQ = BK = 128 keeps every matmul dimension
@@ -28,6 +33,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BQ = 128
 DEFAULT_BK = 128
@@ -65,9 +71,9 @@ def _flash_fwd_kernel(
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)  # (bq, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bk, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)  # (bq, d)
+        k = k_ref[0, 0].astype(jnp.float32)  # (bk, d)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (bq, bk)
@@ -96,7 +102,7 @@ def _flash_fwd_kernel(
     @pl.when(ki == nk - 1)
     def _finish():
         denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def flash_attention_fwd(
@@ -107,33 +113,28 @@ def flash_attention_fwd(
     window: int = 0,
     bq: int = DEFAULT_BQ,
     bk: int = DEFAULT_BK,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     b, sq, h, d = q.shape
     _, sk, kv, _ = k.shape
     group = h // kv
     scale = 1.0 / math.sqrt(d)
 
-    bq = min(bq, max(8, sq))
-    bk = min(bk, max(8, sk))
-    pad_q = (-sq) % bq
-    pad_k = (-sk) % bk
-    if pad_q:
-        q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
-    if pad_k:
-        k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
-    sq_p, sk_p = q.shape[1], k.shape[1]
+    bq = min(bq, pl.cdiv(sq, 8) * 8)
+    bk = min(bk, pl.cdiv(sk, 8) * 8)
+    # heads-major, sequence padded to whole blocks: (B, H, S, D)
+    q = jnp.pad(q.swapaxes(1, 2), ((0, 0), (0, 0), (0, (-sq) % bq), (0, 0)))
+    k = jnp.pad(k.swapaxes(1, 2), ((0, 0), (0, 0), (0, (-sk) % bk), (0, 0)))
+    v = jnp.pad(v.swapaxes(1, 2), ((0, 0), (0, 0), (0, (-sk) % bk), (0, 0)))
+    sq_p, sk_p = q.shape[2], k.shape[2]
     grid = (b * h, sq_p // bq, sk_p // bk)
 
     q_spec = pl.BlockSpec(
-        (1, bq, 1, d), lambda bh, qi, ki: (bh // h, qi, bh % h, 0)
+        (1, 1, bq, d), lambda bh, qi, ki: (bh // h, bh % h, qi, 0)
     )
     kv_spec = pl.BlockSpec(
-        (1, bk, 1, d), lambda bh, qi, ki: (bh // h, ki, (bh % h) // group, 0)
-    )
-    o_spec = pl.BlockSpec(
-        (1, bq, 1, d), lambda bh, qi, ki: (bh // h, qi, bh % h, 0)
+        (1, 1, bk, d), lambda bh, qi, ki: (bh // h, (bh % h) // group, ki, 0)
     )
 
     kernel = functools.partial(
@@ -144,22 +145,13 @@ def flash_attention_fwd(
         kernel,
         grid=grid,
         in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=o_spec,
+        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
-            pltpu_vmem((bq, 1), jnp.float32),
-            pltpu_vmem((bq, 1), jnp.float32),
-            pltpu_vmem((bq, d), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)
-    if pad_q:
-        out = out[:, :sq]
-    return out
-
-
-def pltpu_vmem(shape, dtype):
-    """VMEM scratch allocation (TPU); plain scratch in interpret mode."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM(shape, dtype)
+    return out[:, :, :sq].swapaxes(1, 2)

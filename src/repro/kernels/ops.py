@@ -1,9 +1,11 @@
 """Jit-ready wrappers around the Pallas kernels.
 
+The kernels run compiled on a TPU and in interpret mode anywhere else; this
+module is the one place that chooses (``_on_tpu``).
+
 ``flash_attention`` exposes a jax.custom_vjp op: the forward runs the Pallas
-kernel (interpret=True on CPU, compiled on TPU); the backward rematerializes
-through the jnp reference (exact same math), so models can train with the
-kernel enabled.
+kernel; the backward rematerializes through the jnp reference (exact same
+math), so models can train with the kernel enabled.
 """
 
 from __future__ import annotations
@@ -15,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.kernels import flash_attention as fa
 from repro.kernels import ref
+from repro.kernels import rmsnorm as rms
 
 
 def _on_tpu() -> bool:
@@ -42,3 +45,7 @@ def _bwd(causal, window, res, g):
 
 
 flash_attention.defvjp(_fwd, _bwd)
+
+
+def rmsnorm(x, gamma, eps=1e-6):
+    return rms.rmsnorm(x, gamma, eps, interpret=not _on_tpu())

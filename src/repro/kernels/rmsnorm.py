@@ -23,7 +23,7 @@ def _rmsnorm_kernel(x_ref, g_ref, o_ref, *, eps: float):
 
 
 def rmsnorm(x: jax.Array, gamma: jax.Array, eps: float = 1e-6,
-            block_rows: int = 128, interpret: bool = True) -> jax.Array:
+            block_rows: int = 128, *, interpret: bool) -> jax.Array:
     """x: (..., d); gamma: (d,)."""
     orig_shape = x.shape
     d = x.shape[-1]

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell this script:
@@ -11,6 +8,10 @@ For each cell this script:
   4. records memory_analysis / cost_analysis / per-type collective bytes
      parsed from the optimized HLO into a JSON report.
 
+The meshes are 512 fake CPU devices.  ``main`` sets that up before JAX
+creates its backends, and keeps the run off any accelerator; importing this
+module touches no JAX state.
+
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch all --shape all \
       --mesh both --out results/dryrun.json
@@ -18,20 +19,10 @@ Usage:
 
 import argparse
 import json
+import os
 import re
 import time
 import traceback
-
-import jax
-import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
-
-from repro.configs import (SHAPES, abstract_cache, abstract_params, get_config,
-                           input_specs, list_archs, valid_cells)
-from repro.launch.mesh import make_production_mesh
-from repro.parallel import sharding as shard_lib
-from repro.train import optimizer as opt_lib
-from repro.train import steps as steps_lib
 
 DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3": 1, "f8e5m2": 1,
@@ -108,6 +99,16 @@ def build_cell(arch: str, shape_name: str, mesh, multi_pod: bool, options, smoke
     """Returns (jitted_fn, example_args) ready to lower."""
     import dataclasses
 
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.configs import (SHAPES, abstract_cache, abstract_params,
+                               get_config, input_specs)
+    from repro.parallel import sharding as shard_lib
+    from repro.train import optimizer as opt_lib
+    from repro.train import steps as steps_lib
+
     cfg = cfg_override if cfg_override is not None else get_config(arch, smoke=smoke)
     if moe_mode != "tp" and cfg.family == "moe":
         cfg = dataclasses.replace(cfg, moe_mode=moe_mode)
@@ -183,6 +184,7 @@ def calibrate_cost(arch, shape_name, mesh, multi_pod, options, smoke=False,
     every scan unrolled and extrapolate linearly to the full depth."""
     import dataclasses
 
+    from repro.configs import get_config
     from repro.models import layers as L
 
     cfg = get_config(arch, smoke=smoke)
@@ -214,6 +216,8 @@ def calibrate_cost(arch, shape_name, mesh, multi_pod, options, smoke=False,
 
 def run_cell(arch, shape_name, multi_pod, options, smoke=False, variant_name="",
              **variant):
+    from repro.launch.mesh import make_production_mesh
+
     mesh = make_production_mesh(multi_pod=multi_pod)
     rec = {
         "arch": arch, "shape": shape_name,
@@ -264,6 +268,12 @@ def run_cell(arch, shape_name, multi_pod, options, smoke=False, variant_name="",
 
 
 def main():
+    # before JAX creates its backends: 512 fake host devices, never a chip
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    from repro.configs import list_archs, valid_cells
+    from repro.train import steps as steps_lib
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="all")
     ap.add_argument("--shape", default="all")

@@ -29,10 +29,3 @@ def make_production_mesh(*, multi_pod: bool = False):
         )
     grid = np.array(devices[:n]).reshape(shape)
     return Mesh(grid, axes)
-
-
-def make_test_mesh(shape=(4, 4), axes=("data", "model")):
-    """Small mesh for multi-fake-device tests (JAX-version-portable)."""
-    from repro.launch import compat
-
-    return compat.make_mesh(shape, axes)

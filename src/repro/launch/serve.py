@@ -15,8 +15,42 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.data.pipeline import make_batch
+from repro.launch.cache import enable_compile_cache
 from repro.models import get_model
 from repro.train import steps as steps_lib
+
+
+def make_step(cfg):
+    """The jitted decode step.  The cache is donated: each step writes the
+    next cache into the buffers of the last, so it is held once."""
+    return jax.jit(steps_lib.make_decode_step(cfg), donate_argnums=(1,))
+
+
+def generate(cfg, params, step_fn, prompts, n_decode):
+    """Greedy continuation of ``prompts`` (B, P) by ``n_decode`` tokens.
+
+    Returns ``(tokens (B, n_decode), prefill_s, decode_s)``; both times end
+    when the device has finished.
+    """
+    batch, prompt_len = prompts.shape
+    cache = get_model(cfg).init_cache(cfg, batch, prompt_len + n_decode)
+    # prefill via repeated decode steps (teacher-forced); serious serving
+    # would run a single prefill forward — decode_32k / long_500k in the
+    # dry-run measure the steady-state decode step this loop exercises.
+    t0 = time.perf_counter()
+    tok = None
+    for t in range(prompt_len):
+        tok, cache = step_fn(params, cache, jnp.asarray(prompts[:, t:t + 1]))
+    tok.block_until_ready()
+    prefill_s = time.perf_counter() - t0
+
+    t0 = time.perf_counter()
+    out = []
+    for _ in range(n_decode):
+        tok, cache = step_fn(params, cache, tok)
+        out.append(tok)
+    tokens = np.asarray(jnp.concatenate(out, axis=1))
+    return tokens, prefill_s, time.perf_counter() - t0
 
 
 def main():
@@ -26,36 +60,19 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--decode", type=int, default=64)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
-    model = get_model(cfg)
-    params = model.init_params(cfg, jax.random.PRNGKey(0))
-    max_len = args.prompt_len + args.decode
-    cache = model.init_cache(cfg, args.batch, max_len)
-    serve_step = jax.jit(steps_lib.make_decode_step(cfg))
-
+    params = get_model(cfg).init_params(cfg, jax.random.PRNGKey(0))
     prompts = make_batch(cfg, args.prompt_len, args.batch)["tokens"]
-    # prefill via repeated decode steps (teacher-forced); serious serving
-    # would run a single prefill forward — decode_32k / long_500k in the
-    # dry-run measure the steady-state decode step this loop exercises.
-    t0 = time.time()
-    tok = None
-    for t in range(args.prompt_len):
-        tok, cache = serve_step(params, cache, jnp.asarray(prompts[:, t:t + 1]))
-    prefill_s = time.time() - t0
-
-    t0 = time.time()
-    out = []
-    for _ in range(args.decode):
-        tok, cache = serve_step(params, cache, tok)
-        out.append(np.asarray(tok)[:, 0])
-    decode_s = time.time() - t0
+    tokens, prefill_s, decode_s = generate(cfg, params, make_step(cfg),
+                                           prompts, args.decode)
     toks_per_s = args.batch * args.decode / decode_s
     print(f"[serve] arch={cfg.name} batch={args.batch} "
           f"prefill {args.prompt_len} toks in {prefill_s:.2f}s; "
           f"decoded {args.decode} toks/seq in {decode_s:.2f}s "
           f"({toks_per_s:.1f} tok/s)")
-    print(f"[serve] sample continuation: {np.stack(out, 1)[0][:16].tolist()}")
+    print(f"[serve] sample continuation: {tokens[0][:16].tolist()}")
 
 
 if __name__ == "__main__":

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.launch import compat
 
 GROUP_TOKENS = 4096  # tokens per dispatch group (bounds the capacity buffer)
 
@@ -165,7 +164,7 @@ def moe_apply_ep(x, params, top_k: int, capacity_factor: float, axis: str = "mod
     experts) and tokens sharded over the data axes.
     """
     b, s, d = x.shape
-    n_dev = compat.axis_size(axis)
+    n_dev = lax.axis_size(axis)
     e_local = params["w_gate"].shape[0]
     e = e_local * n_dev
     t = b * s

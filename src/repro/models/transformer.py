@@ -177,9 +177,7 @@ def _moe_ep(cfg: ArchConfig, mp, x, mesh):
         "router": P(),
         "w_gate": P("model"), "w_up": P("model"), "w_down": P("model"),
     }
-    from repro.launch import compat
-
-    return compat.shard_map(
+    return jax.shard_map(
         f, mesh=mesh, in_specs=(P(), w_specs), out_specs=(P(), P()),
         axis_names={"model"}, check_vma=False,
     )(x, mp)

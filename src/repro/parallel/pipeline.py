@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.launch import compat
-
 
 def pipeline_forward(stage_fn, stage_params, x_micro, axis: str):
     """Run inside shard_map (manual over ``axis``).
@@ -33,7 +31,7 @@ def pipeline_forward(stage_fn, stage_params, x_micro, axis: str):
                                         stage; only stage 0 reads it)
     Returns (M, mb, ...) outputs valid on the LAST stage (others zeros).
     """
-    p = compat.axis_size(axis)
+    p = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     m = x_micro.shape[0]
     fwd = [(i, i + 1) for i in range(p - 1)]
@@ -69,7 +67,7 @@ def make_pipelined_loss(stage_fn, final_fn, axis: str):
     """
 
     def f(stage_params, x_micro, labels_micro):
-        p = compat.axis_size(axis)
+        p = lax.axis_size(axis)
         idx = lax.axis_index(axis)
         outs = pipeline_forward(stage_fn, stage_params, x_micro, axis)
         loss = final_fn(outs, labels_micro)

@@ -25,7 +25,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.core import collectives as coll
-from repro.launch import compat
 from repro.models import get_model
 from repro.parallel.sharding import Policy
 from repro.train import optimizer as opt
@@ -143,12 +142,6 @@ def make_train_step(cfg: ArchConfig, ocfg: opt.AdamWConfig, options: TrainOption
     data_axes = policy.data_axes
     dp_shape = tuple(mesh.shape[a] for a in data_axes)
     algo = options.sync
-    # Manual over *all* mesh axes unless model-parallel activation anchors
-    # need the model axis auto.  Without anchors the model axis carries
-    # replicated compute either way, and full-manual avoids the partial-manual
-    # lowering that legacy JAX/XLA (0.4.x) cannot compile (axis_index →
-    # PartitionId is unsupported under partial SPMD manual sharding).
-    manual_axes = set(data_axes) if act_specs else None
     # inside the manual region, activation anchors may only reference the
     # remaining *auto* axes — strip the (manual) data axes from the specs.
     if act_specs:
@@ -203,13 +196,12 @@ def make_train_step(cfg: ArchConfig, ocfg: opt.AdamWConfig, options: TrainOption
         return n
 
     def train_step(params, opt_state, batch):
-        batch_in_specs = jax.tree.map(lambda _: P(policy.dp), batch)
-        grads_fn = compat.shard_map(
+        grads_fn = jax.shard_map(
             synced_grads,
             mesh=mesh,
             in_specs=(P(), jax.tree.map(lambda _: P(policy.dp), batch)),
             out_specs=(P(), P(), P()),
-            axis_names=manual_axes,
+            axis_names=set(data_axes),
             check_vma=False,
         )
         grads, loss, aux = grads_fn(params, batch)

@@ -15,14 +15,13 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=16")
 import numpy as np  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.core import collectives as coll  # noqa: E402
-from repro.launch import compat  # noqa: E402
 
 
 def make_mesh(shape=(4, 4), names=("data", "model")):
-    return compat.make_mesh(shape, names)
+    return jax.make_mesh(shape, names, axis_types=(AxisType.Auto,) * len(names))
 
 
 def check_allreduce_algorithms():
@@ -30,7 +29,7 @@ def check_allreduce_algorithms():
     x = jnp.arange(16 * 37, dtype=jnp.float32).reshape(16, 37) / 7.0
 
     ref_fn = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             lambda v: jax.lax.psum(v, ("data", "model")),
             mesh=mesh, check_vma=False, in_specs=P("data", None), out_specs=P("data", None),
         )
@@ -39,7 +38,7 @@ def check_allreduce_algorithms():
 
     for algo in ("ring", "bidir", "torus", "hamiltonian"):
         fn = jax.jit(
-            compat.shard_map(
+            jax.shard_map(
                 lambda v, a=algo: coll.allreduce(v, a, ("data", "model"), (4, 4)),
                 mesh=mesh, check_vma=False, in_specs=P("data", None), out_specs=P("data", None),
             )
@@ -48,17 +47,32 @@ def check_allreduce_algorithms():
         np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
         print(f"OK allreduce:{algo}")
 
+    # a 2x2 mesh (one four-chip host): hamiltonian has no edge-disjoint
+    # cycle pair there and runs over a single cycle
+    mesh22 = make_mesh((2, 2), ("r", "c"))
+    x22 = jax.random.normal(jax.random.PRNGKey(3), (4, 1000))
+    spec = P(("r", "c"), None)
+    ref22 = jax.jit(jax.shard_map(
+        lambda v: jax.lax.psum(v, ("r", "c")),
+        mesh=mesh22, check_vma=False, in_specs=spec, out_specs=spec))(x22)
+    for algo in ("ring", "bidir", "torus", "hamiltonian"):
+        out = jax.jit(jax.shard_map(
+            lambda v, a=algo: coll.allreduce(v, a, ("r", "c"), (2, 2)),
+            mesh=mesh22, check_vma=False, in_specs=spec, out_specs=spec))(x22)
+        np.testing.assert_allclose(out, ref22, rtol=1e-5, atol=1e-5)
+        print(f"OK allreduce2x2:{algo}")
+
     # 1D variants over a single axis
     x1 = jnp.arange(16 * 64, dtype=jnp.float32).reshape(16, 64) / 7.0
     ref1 = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             lambda v: jax.lax.psum(v, "model"),
             mesh=mesh, check_vma=False, in_specs=P("data", "model"), out_specs=P("data", "model"),
         )
     )(x1)
     for algo in ("ring", "bidir"):
         out = jax.jit(
-            compat.shard_map(
+            jax.shard_map(
                 lambda v, a=algo: coll.allreduce(v, a, ("model",)),
                 mesh=mesh, check_vma=False, in_specs=P("data", "model"), out_specs=P("data", "model"),
             )
@@ -76,10 +90,10 @@ def check_reduce_scatter_allgather():
         return coll.ring_all_gather(chunk, "r").reshape(v.shape)
 
     out = jax.jit(
-        compat.shard_map(rs_ag, mesh=mesh, check_vma=False, in_specs=P("r", None), out_specs=P("r", None))
+        jax.shard_map(rs_ag, mesh=mesh, check_vma=False, in_specs=P("r", None), out_specs=P("r", None))
     )(x)
     ref = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             lambda v: jax.lax.psum(v, "r"),
             mesh=mesh, check_vma=False, in_specs=P("r", None), out_specs=P("r", None),
         )
@@ -99,7 +113,7 @@ def check_allreduce_tree():
         return coll.allreduce_tree(t, "torus", ("data", "model"), (4, 4), mean=True)
 
     out = jax.jit(
-        compat.shard_map(f, mesh=mesh, check_vma=False, in_specs=(P(),), out_specs=P())
+        jax.shard_map(f, mesh=mesh, check_vma=False, in_specs=(P(),), out_specs=P())
     )(tree)
     # replicated inputs -> mean over 16 identical copies == identity
     np.testing.assert_allclose(out["w"], tree["w"], rtol=1e-5)
@@ -121,7 +135,7 @@ def check_compression():
         return out, st2.residual
 
     out, resid = jax.jit(
-        compat.shard_map(f, mesh=mesh, check_vma=False, in_specs=P("d", None), out_specs=P("d", None))
+        jax.shard_map(f, mesh=mesh, check_vma=False, in_specs=P("d", None), out_specs=P("d", None))
     )(g)
     # sparse allreduce + residual must preserve the total gradient mass:
     # sum over devices of (sent + residual) == sum of raw gradients
@@ -139,7 +153,7 @@ def check_hlo_collective_bytes():
     mesh = make_mesh()
     x = jax.ShapeDtypeStruct((16, 1024), jnp.float32)
     lo = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             lambda v: coll.ring_allreduce(v, "model"),
             mesh=mesh, check_vma=False, in_specs=P("data", "model"), out_specs=P("data", "model"),
         )
@@ -173,7 +187,7 @@ def check_collective_train_step():
             cfg, ocfg, steps_lib.TrainOptions(remat=False), policy
         )
     )
-    with compat.use_mesh(mesh):  # Mesh context on 0.4.x, jax.set_mesh on new
+    with jax.set_mesh(mesh):
         p_ref, _, m_ref = ref_step(params, opt.init(params), batch)
 
     # 1-axis algorithms over "data"; 2-axis over the full (data, model) grid
@@ -184,7 +198,7 @@ def check_collective_train_step():
         step = steps_lib.make_train_step(
             cfg, ocfg, steps_lib.TrainOptions(remat=False, sync=algo), pol, mesh
         )
-        with compat.use_mesh(mesh):  # Mesh context on 0.4.x, set_mesh on new
+        with jax.set_mesh(mesh):
             p_new, _, m_new = jax.jit(step)(params, opt.init(params), batch)
         for a, b in zip(jax.tree.leaves(p_ref), jax.tree.leaves(p_new)):
             np.testing.assert_allclose(
@@ -207,7 +221,7 @@ def check_pipeline_parallel():
         return jnp.tanh(h @ w)
 
     run = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             lambda w, xx: pp.pipeline_forward(stage, w[0], xx, "pipe"),
             mesh=mesh, check_vma=False,
             in_specs=(P("pipe", None, None), P(None, None, None)),
@@ -219,11 +233,11 @@ def check_pipeline_parallel():
     def run_fn(w, xx):
         out = pp.pipeline_forward(stage, w[0], xx, "pipe")
         idx = jax.lax.axis_index("pipe")
-        out = jnp.where(idx == compat.axis_size("pipe") - 1, out, 0.0)
+        out = jnp.where(idx == jax.lax.axis_size("pipe") - 1, out, 0.0)
         return jax.lax.psum(out, "pipe")
 
     run = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             run_fn, mesh=mesh, check_vma=False,
             in_specs=(P("pipe", None, None), P(None, None, None)),
             out_specs=P(None, None, None),
@@ -243,11 +257,11 @@ def check_pipeline_parallel():
     def loss(w, xx):
         out = pp.pipeline_forward(stage, w[0], xx, "pipe")
         idx = jax.lax.axis_index("pipe")
-        out = jnp.where(idx == compat.axis_size("pipe") - 1, out, 0.0)
+        out = jnp.where(idx == jax.lax.axis_size("pipe") - 1, out, 0.0)
         return jnp.mean(out**2)
 
     g = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             jax.grad(loss), mesh=mesh, check_vma=False,
             in_specs=(P("pipe", None, None), P(None, None, None)),
             out_specs=P("pipe", None, None),
@@ -287,7 +301,7 @@ def check_moe_ep():
         return y
 
     y_ep = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             ep, mesh=mesh, check_vma=False,
             in_specs=(P(None, None, None),
                       {"router": P(None, None), "w_gate": P("model", None, None),

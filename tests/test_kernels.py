@@ -1,4 +1,4 @@
-"""Pallas flash-attention kernel vs the pure-jnp oracle (interpret mode)."""
+"""Pallas kernels vs the pure-jnp oracles (interpret mode off the TPU)."""
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +17,10 @@ CASES = [
     (1, 128, 128, 4, 4, 128, True, 0, jnp.float32, 2e-5),  # d=128 (MXU width)
     (1, 128, 128, 4, 4, 64, True, 0, jnp.bfloat16, 3e-2),
     (2, 128, 128, 2, 1, 64, False, 32, jnp.bfloat16, 3e-2),
+    # published head layouts, at the edges of the heads-major blocks
+    (1, 256, 256, 36, 36, 64, True, 0, jnp.float32, 2e-5),   # minicpm-2b, d=64
+    (1, 256, 256, 36, 36, 64, True, 0, jnp.bfloat16, 3e-2),
+    (1, 256, 256, 32, 8, 128, True, 0, jnp.float32, 2e-5),   # granite-8b GQA
 ]
 
 
@@ -83,12 +87,10 @@ RMS_CASES = [
 
 @pytest.mark.parametrize("case", RMS_CASES, ids=[str(c) for c in RMS_CASES])
 def test_rmsnorm_kernel_vs_oracle(case):
-    from repro.kernels.rmsnorm import rmsnorm as k_rms
-
     shape, dtype = case
     x = jax.random.normal(jax.random.PRNGKey(0), shape, dtype)
     g = jax.random.normal(jax.random.PRNGKey(1), (shape[-1],), jnp.float32) * 0.1
-    out = k_rms(x, g)
+    out = ops.rmsnorm(x, g)
     want = ref.rmsnorm_ref(x, g)
     rtol = 2e-2 if dtype == jnp.bfloat16 else 2e-6
     np.testing.assert_allclose(

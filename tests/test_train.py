@@ -99,3 +99,53 @@ def test_grad_clip():
     clipped, norm = opt.clip_by_global_norm(g, 1.0)
     assert float(norm) == 200.0
     np.testing.assert_allclose(float(jnp.linalg.norm(clipped["w"])), 1.0, rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# launch entry points (the path chip_smoke.py drives at real widths)
+# ---------------------------------------------------------------------------
+
+
+def test_launch_train_loop_descends_and_donates():
+    from repro.launch import train
+
+    mesh, params, ostate, step_fn = train.build(CFG, steps=6, lr=1e-2)
+    first = jax.tree.leaves(params)[0]
+    losses = []
+    for n, params, ostate, m in train.train_loop(
+            CFG, mesh, step_fn, params, ostate, start=0, stop=6, seq=32, batch=4):
+        losses.append(float(m["loss"]))
+    assert n == 6 and all(np.isfinite(losses)) and losses[-1] < losses[0]
+    assert first.is_deleted(), "params were not donated to the step"
+
+
+def test_launch_serve_generate_donates_cache():
+    from repro.launch import serve
+
+    model = get_model(CFG)
+    params = model.init_params(CFG, jax.random.PRNGKey(0))
+    prompts = make_batch(CFG, 8, 3)["tokens"]
+    step = serve.make_step(CFG)
+    tokens, prefill_s, decode_s = serve.generate(CFG, params, step, prompts, 5)
+    assert tokens.shape == (3, 5) and ((tokens >= 0) & (tokens < CFG.vocab)).all()
+    cache = model.init_cache(CFG, 3, 13)
+    step(params, cache, jnp.asarray(prompts[:, :1]))
+    assert cache["k"].is_deleted(), "the cache was not donated to the step"
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    from pathlib import Path
+
+    from repro.launch import cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before  # left to JAX
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        assert cache.enable_compile_cache() == str(cache.REPO_CACHE)
+        assert jax.config.jax_compilation_cache_dir == str(cache.REPO_CACHE)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert cache.REPO_CACHE == Path(__file__).resolve().parents[1] / ".jax_cache"
