@@ -1,0 +1,6 @@
+"""Share of the traced serving window in which no operation ran on the
+device (1 - union of op intervals / window)."""
+
+
+def read(trace, inputs, peaks, config):
+    return 100.0 * trace.idle_share
