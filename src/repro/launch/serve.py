@@ -17,6 +17,7 @@ from repro.configs import get_config
 from repro.data.pipeline import make_batch
 from repro.launch.cache import enable_compile_cache
 from repro.models import get_model
+from repro.obs import device as OD
 from repro.train import steps as steps_lib
 
 
@@ -30,27 +31,38 @@ def generate(cfg, params, step_fn, prompts, n_decode):
     """Greedy continuation of ``prompts`` (B, P) by ``n_decode`` tokens.
 
     Returns ``(tokens (B, n_decode), prefill_s, decode_s)``; both times end
-    when the device has finished.
+    when the device has finished.  Each call, phase and step is a host span
+    on the profiler's trace (``repro.obs.device``).
     """
     batch, prompt_len = prompts.shape
-    cache = get_model(cfg).init_cache(cfg, batch, prompt_len + n_decode)
-    # prefill via repeated decode steps (teacher-forced); serious serving
-    # would run a single prefill forward — decode_32k / long_500k in the
-    # dry-run measure the steady-state decode step this loop exercises.
-    t0 = time.perf_counter()
-    tok = None
-    for t in range(prompt_len):
-        tok, cache = step_fn(params, cache, jnp.asarray(prompts[:, t:t + 1]))
-    tok.block_until_ready()
-    prefill_s = time.perf_counter() - t0
+    OD.trace_gc()
+    with OD.span(OD.SERVE_GENERATE, call=OD.next_call(), batch=batch,
+                 prompt=prompt_len, output=n_decode):
+        cache = get_model(cfg).init_cache(cfg, batch, prompt_len + n_decode)
+        # prefill via repeated decode steps (teacher-forced); serious serving
+        # would run a single prefill forward — decode_32k / long_500k in the
+        # dry-run measure the steady-state decode step this loop exercises.
+        t0 = time.perf_counter()
+        tok = None
+        with OD.span(OD.SERVE_PREFILL, batch=batch, prompt=prompt_len):
+            for t in range(prompt_len):
+                with OD.span(OD.SERVE_STEP, step=t):
+                    with OD.span(OD.SERVE_H2D):
+                        x = jnp.asarray(prompts[:, t:t + 1])
+                    tok, cache = step_fn(params, cache, x)
+            tok.block_until_ready()
+        prefill_s = time.perf_counter() - t0
 
-    t0 = time.perf_counter()
-    out = []
-    for _ in range(n_decode):
-        tok, cache = step_fn(params, cache, tok)
-        out.append(tok)
-    tokens = np.asarray(jnp.concatenate(out, axis=1))
-    return tokens, prefill_s, time.perf_counter() - t0
+        t0 = time.perf_counter()
+        out = []
+        with OD.span(OD.SERVE_DECODE, batch=batch, output=n_decode):
+            for i in range(n_decode):
+                with OD.span(OD.SERVE_STEP, step=prompt_len + i):
+                    tok, cache = step_fn(params, cache, tok)
+                out.append(tok)
+            with OD.span(OD.SERVE_FETCH):
+                tokens = np.asarray(jnp.concatenate(out, axis=1))
+        return tokens, prefill_s, time.perf_counter() - t0
 
 
 def main():

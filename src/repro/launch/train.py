@@ -26,6 +26,7 @@ from repro.core import allocation as alloc_lib
 from repro.data.pipeline import make_batch
 from repro.launch.cache import enable_compile_cache
 from repro.models import get_model
+from repro.obs import device as OD
 from repro.parallel.sharding import Policy
 from repro.train import optimizer as opt_lib
 from repro.train import steps as steps_lib
@@ -73,10 +74,18 @@ def place_batch(batch, mesh):
 def train_loop(cfg, mesh, step_fn, params, opt_state, *, start, stop, seq,
                batch, seed=0):
     """Steps ``start`` .. ``stop - 1`` on the synthetic batches; yields
-    ``(steps_done, params, opt_state, metrics)`` after each step."""
+    ``(steps_done, params, opt_state, metrics)`` after each step.  Each step
+    is a profiler step, and its batch, placement and dispatch are host spans
+    (``repro.obs.device``)."""
+    OD.trace_gc()
     for step in range(start, stop):
-        b = place_batch(make_batch(cfg, seq, batch, step=step, seed=seed), mesh)
-        params, opt_state, metrics = step_fn(params, opt_state, b)
+        with jax.profiler.StepTraceAnnotation(OD.TRAIN_STEPS, step_num=step):
+            with OD.span(OD.TRAIN_BATCH, step=step):
+                rows = make_batch(cfg, seq, batch, step=step, seed=seed)
+            with OD.span(OD.TRAIN_PLACE):
+                b = place_batch(rows, mesh)
+            with OD.span(OD.TRAIN_STEP):
+                params, opt_state, metrics = step_fn(params, opt_state, b)
         yield step + 1, params, opt_state, metrics
 
 

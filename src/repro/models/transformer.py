@@ -20,6 +20,7 @@ from jax import lax
 from repro.configs.base import ArchConfig
 from repro.models import layers as L
 from repro.models import moe as moe_lib
+from repro.obs import device as OD
 
 
 # ---------------------------------------------------------------------------
@@ -143,6 +144,12 @@ def _apply_pos(cfg, q, k, positions):
     return q, k
 
 
+def _norm(cfg: ArchConfig, x, p):
+    with jax.named_scope(OD.NORM):
+        return L.apply_norm(x, p, cfg.norm_type)
+
+
+@jax.named_scope(OD.ATTENTION)
 def _attn_block(cfg: ArchConfig, p, x, positions, causal, window, kv_seq=None,
                 use_kernel=False):
     """p holds per-layer (unstacked) attention params."""
@@ -183,6 +190,7 @@ def _moe_ep(cfg: ArchConfig, mp, x, mesh):
     )(x, mp)
 
 
+@jax.named_scope(OD.MLP)
 def _mlp_block(cfg: ArchConfig, p, x):
     if cfg.act == "swiglu":
         return L.swiglu(x, p["w_gate"], p["w_up"], p["w_down"])
@@ -216,9 +224,10 @@ def forward(
             )
         )
     act = (act_specs or {}).get("act")
-    x = L.constrain(params["embed"][tokens], act)
-    if cfg.rope_type == "learned":
-        x = x + params["pos_embed"][: x.shape[1]][None]
+    with jax.named_scope(OD.EMBED):
+        x = L.constrain(params["embed"][tokens], act)
+        if cfg.rope_type == "learned":
+            x = x + params["pos_embed"][: x.shape[1]][None]
 
     enc_out = None
     if cfg.enc_layers:
@@ -227,42 +236,44 @@ def forward(
 
     def layer_fn(carry, lp):
         h, aux = carry
-        a = L.apply_norm(h, lp["attn_norm"], cfg.norm_type)
-        h = h + _attn_block(cfg, lp, a, positions, causal=True, window=0,
-                            use_kernel=use_kernel)
+        h = h + _attn_block(cfg, lp, _norm(cfg, h, lp["attn_norm"]), positions,
+                            causal=True, window=0, use_kernel=use_kernel)
         if enc_out is not None:
-            xa = L.apply_norm(h, lp["xattn_norm"], cfg.norm_type)
+            xa = _norm(cfg, h, lp["xattn_norm"])
             xp = {k[1:]: v for k, v in lp.items() if k.startswith("x") and k != "xattn_norm"}
             h = h + _attn_block(cfg, xp, xa, positions, causal=False, window=0,
                                 kv_seq=enc_out)
-        m = L.apply_norm(h, lp["mlp_norm"], cfg.norm_type)
+        m = _norm(cfg, h, lp["mlp_norm"])
         if cfg.family == "moe":
-            if cfg.moe_mode == "ep":
-                y, a_loss = _moe_ep(cfg, lp["moe"], m, (act_specs or {}).get("mesh"))
-            elif cfg.moe_mode == "gshard":
-                y, a_loss = moe_lib.moe_apply_gshard(
-                    m, lp["moe"], cfg.top_k, cfg.capacity_factor,
-                    expert_spec=(act_specs or {}).get("experts"))
-            else:
-                y, a_loss = moe_lib.moe_apply(m, lp["moe"], cfg.top_k,
-                                              cfg.capacity_factor)
+            with jax.named_scope(OD.MLP):
+                if cfg.moe_mode == "ep":
+                    y, a_loss = _moe_ep(cfg, lp["moe"], m, (act_specs or {}).get("mesh"))
+                elif cfg.moe_mode == "gshard":
+                    y, a_loss = moe_lib.moe_apply_gshard(
+                        m, lp["moe"], cfg.top_k, cfg.capacity_factor,
+                        expert_spec=(act_specs or {}).get("experts"))
+                else:
+                    y, a_loss = moe_lib.moe_apply(m, lp["moe"], cfg.top_k,
+                                                  cfg.capacity_factor)
             aux = aux + a_loss
         else:
             y = _mlp_block(cfg, lp, m)
         return (L.constrain(h + y, act), aux), None
 
     body = jax.checkpoint(layer_fn) if remat else layer_fn
-    (x, aux), _ = lax.scan(body, (x, jnp.float32(0.0)), params["layers"],
-                           unroll=L.scan_unroll(cfg.n_layers))
-    x = L.apply_norm(x, params["final_norm"], cfg.norm_type)
-    if return_hidden:
-        return x, aux / cfg.n_layers
-    unembed = params.get("unembed", params["embed"].T)
-    logits = jnp.einsum("bsd,dv->bsv", x, unembed)
-    if logits.shape[-1] != cfg.vocab:  # TP-padded vocab: mask the tail
-        keep = jnp.arange(logits.shape[-1]) < cfg.vocab
-        logits = jnp.where(keep, logits, jnp.asarray(-1e30, logits.dtype))
-    logits = L.constrain(logits, (act_specs or {}).get("logits"))
+    with jax.named_scope(OD.LAYERS):
+        (x, aux), _ = lax.scan(body, (x, jnp.float32(0.0)), params["layers"],
+                               unroll=L.scan_unroll(cfg.n_layers))
+    with jax.named_scope(OD.UNEMBED):
+        x = L.apply_norm(x, params["final_norm"], cfg.norm_type)
+        if return_hidden:
+            return x, aux / cfg.n_layers
+        unembed = params.get("unembed", params["embed"].T)
+        logits = jnp.einsum("bsd,dv->bsv", x, unembed)
+        if logits.shape[-1] != cfg.vocab:  # TP-padded vocab: mask the tail
+            keep = jnp.arange(logits.shape[-1]) < cfg.vocab
+            logits = jnp.where(keep, logits, jnp.asarray(-1e30, logits.dtype))
+        logits = L.constrain(logits, (act_specs or {}).get("logits"))
     return logits, aux / cfg.n_layers
 
 
@@ -271,13 +282,13 @@ def _encoder_forward(cfg: ArchConfig, enc, frames, remat):
     pos = _positions_default(frames[..., 0].astype(jnp.int32))
 
     def layer_fn(h, lp):
-        a = L.apply_norm(h, lp["attn_norm"], cfg.norm_type)
-        h = h + _attn_block(cfg, lp, a, pos, causal=False, window=0)
-        m = L.apply_norm(h, lp["mlp_norm"], cfg.norm_type)
-        return h + _mlp_block(cfg, lp, m), None
+        h = h + _attn_block(cfg, lp, _norm(cfg, h, lp["attn_norm"]), pos, causal=False,
+                            window=0)
+        return h + _mlp_block(cfg, lp, _norm(cfg, h, lp["mlp_norm"])), None
 
     body = jax.checkpoint(layer_fn) if remat else layer_fn
-    x, _ = lax.scan(body, x, enc["layers"], unroll=L.scan_unroll(cfg.enc_layers))
+    with jax.named_scope(OD.LAYERS):
+        x, _ = lax.scan(body, x, enc["layers"], unroll=L.scan_unroll(cfg.enc_layers))
     return L.apply_norm(x, enc["final_norm"], cfg.norm_type)
 
 
@@ -310,32 +321,38 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, positions=None):
             positions = jnp.broadcast_to(pos_scalar.astype(jnp.int32), (3, b, 1))
         else:
             positions = jnp.broadcast_to(pos_scalar.astype(jnp.int32), (b, 1))
-    x = params["embed"][tokens]
-    if cfg.rope_type == "learned":
-        x = x + lax.dynamic_slice_in_dim(params["pos_embed"], pos_scalar, 1)[None]
+    with jax.named_scope(OD.EMBED):
+        x = params["embed"][tokens]
+        if cfg.rope_type == "learned":
+            x = x + lax.dynamic_slice_in_dim(params["pos_embed"], pos_scalar, 1)[None]
 
     def layer_fn(carry, lp_and_cache):
         h, li = carry
         lp, kc, vc, xk, xv = lp_and_cache
-        a = L.apply_norm(h, lp["attn_norm"], cfg.norm_type)
-        q = jnp.einsum("bsd,dq->bsq", a, lp["wq"]).reshape(b, 1, h_, hd)
-        k = jnp.einsum("bsd,dq->bsq", a, lp["wk"]).reshape(b, 1, kv, hd)
-        v = jnp.einsum("bsd,dq->bsq", a, lp["wv"]).reshape(b, 1, kv, hd)
-        if cfg.rope_type in ("rope", "mrope"):
-            q, k = _apply_pos(cfg, q, k, positions)
-        kc = lax.dynamic_update_slice_in_dim(kc, k.astype(kc.dtype), pos_scalar, axis=1)
-        vc = lax.dynamic_update_slice_in_dim(vc, v.astype(vc.dtype), pos_scalar, axis=1)
-        o = L.attention_decode(q, kc, vc, pos_scalar + 1,
-                               window=cfg.local_window if cfg.family == "vlm" else 0)
-        h = h + jnp.einsum("bsq,qd->bsd", o.reshape(b, 1, h_ * hd), lp["wo"])
+        a = _norm(cfg, h, lp["attn_norm"])
+        with jax.named_scope(OD.ATTENTION):
+            q = jnp.einsum("bsd,dq->bsq", a, lp["wq"]).reshape(b, 1, h_, hd)
+            k = jnp.einsum("bsd,dq->bsq", a, lp["wk"]).reshape(b, 1, kv, hd)
+            v = jnp.einsum("bsd,dq->bsq", a, lp["wv"]).reshape(b, 1, kv, hd)
+            if cfg.rope_type in ("rope", "mrope"):
+                q, k = _apply_pos(cfg, q, k, positions)
+            kc = lax.dynamic_update_slice_in_dim(kc, k.astype(kc.dtype), pos_scalar, axis=1)
+            vc = lax.dynamic_update_slice_in_dim(vc, v.astype(vc.dtype), pos_scalar, axis=1)
+            o = L.attention_decode(q, kc, vc, pos_scalar + 1,
+                                   window=cfg.local_window if cfg.family == "vlm" else 0)
+            attn = jnp.einsum("bsq,qd->bsd", o.reshape(b, 1, h_ * hd), lp["wo"])
+        h = h + attn
         if cfg.enc_layers:
-            xa = L.apply_norm(h, lp["xattn_norm"], cfg.norm_type)
-            qx = jnp.einsum("bsd,dq->bsq", xa, lp["xwq"]).reshape(b, 1, h_, hd)
-            o = L.attention_decode(qx, xk, xv, xk.shape[1])
-            h = h + jnp.einsum("bsq,qd->bsd", o.reshape(b, 1, h_ * hd), lp["xwo"])
-        m = L.apply_norm(h, lp["mlp_norm"], cfg.norm_type)
+            xa = _norm(cfg, h, lp["xattn_norm"])
+            with jax.named_scope(OD.ATTENTION):
+                qx = jnp.einsum("bsd,dq->bsq", xa, lp["xwq"]).reshape(b, 1, h_, hd)
+                o = L.attention_decode(qx, xk, xv, xk.shape[1])
+                attn = jnp.einsum("bsq,qd->bsd", o.reshape(b, 1, h_ * hd), lp["xwo"])
+            h = h + attn
+        m = _norm(cfg, h, lp["mlp_norm"])
         if cfg.family == "moe":
-            y, _ = moe_lib.moe_apply(m, lp["moe"], cfg.top_k, cfg.capacity_factor)
+            with jax.named_scope(OD.MLP):
+                y, _ = moe_lib.moe_apply(m, lp["moe"], cfg.top_k, cfg.capacity_factor)
         else:
             y = _mlp_block(cfg, lp, m)
         return (h + y, li + 1), (kc, vc)
@@ -343,11 +360,13 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, positions=None):
     lp = params["layers"]
     xk = cache.get("xk", jnp.zeros((cfg.n_layers, b, 1, kv, hd), jnp.bfloat16))
     xv = cache.get("xv", xk)
-    (x, _), (new_k, new_v) = lax.scan(
-        layer_fn, (x, 0), (lp, cache["k"], cache["v"], xk, xv)
-    )
-    x = L.apply_norm(x, params["final_norm"], cfg.norm_type)
-    unembed = params.get("unembed", params["embed"].T)
-    logits = jnp.einsum("bsd,dv->bsv", x, unembed)
+    with jax.named_scope(OD.LAYERS):
+        (x, _), (new_k, new_v) = lax.scan(
+            layer_fn, (x, 0), (lp, cache["k"], cache["v"], xk, xv)
+        )
+    with jax.named_scope(OD.UNEMBED):
+        x = L.apply_norm(x, params["final_norm"], cfg.norm_type)
+        unembed = params.get("unembed", params["embed"].T)
+        logits = jnp.einsum("bsd,dv->bsv", x, unembed)
     new_cache = dict(cache, k=new_k, v=new_v, len=pos_scalar + 1)
     return logits, new_cache

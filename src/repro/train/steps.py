@@ -26,6 +26,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ArchConfig
 from repro.core import collectives as coll
 from repro.models import get_model
+from repro.obs import device as OD
 from repro.parallel.sharding import Policy
 from repro.train import optimizer as opt
 
@@ -71,14 +72,16 @@ def make_loss_fn(cfg: ArchConfig, options: TrainOptions, act_specs=None):
                 return_hidden=True, **extras,
             )
             unembed = params.get("unembed", params["embed"].T)
-            loss = chunked_cross_entropy(
-                hidden, unembed, batch["labels"], cfg.vocab, options.ce_chunk)
+            with jax.named_scope(OD.LOSS):
+                loss = chunked_cross_entropy(
+                    hidden, unembed, batch["labels"], cfg.vocab, options.ce_chunk)
         else:
             logits, aux = model.forward(
                 cfg, params, batch["tokens"], remat=options.remat,
                 use_kernel=options.use_kernel, act_specs=act_specs, **extras,
             )
-            loss = cross_entropy(logits, batch["labels"])
+            with jax.named_scope(OD.LOSS):
+                loss = cross_entropy(logits, batch["labels"])
         return loss + options.moe_aux_weight * aux, (loss, aux)
 
     return loss_fn
@@ -132,7 +135,8 @@ def make_train_step(cfg: ArchConfig, ocfg: opt.AdamWConfig, options: TrainOption
             (tot, (loss, aux)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 params, batch
             )
-            new_params, new_state, m = opt.apply(ocfg, opt_state, params, grads)
+            with jax.named_scope(OD.OPTIMIZER):
+                new_params, new_state, m = opt.apply(ocfg, opt_state, params, grads)
             return new_params, new_state, {"loss": loss, "aux": aux, **m}
 
         return train_step
@@ -170,23 +174,24 @@ def make_train_step(cfg: ArchConfig, ocfg: opt.AdamWConfig, options: TrainOption
             params, batch
         )
         axes = data_axes if len(data_axes) > 1 else (data_axes[0],)
-        if options.compress_k:
-            from repro.core import compression as comp
+        with jax.named_scope(OD.GRAD_SYNC):
+            if options.compress_k:
+                from repro.core import compression as comp
 
-            def sync_leaf(g):
-                st = comp.init_state(g)  # stateless variant: residual dropped
-                out, _ = comp.sparse_allreduce(
-                    g.astype(jnp.float32), st, options.compress_k, axes[0]
-                )
-                return (out / dp_total(axes)).astype(g.dtype)
+                def sync_leaf(g):
+                    st = comp.init_state(g)  # stateless variant: residual dropped
+                    out, _ = comp.sparse_allreduce(
+                        g.astype(jnp.float32), st, options.compress_k, axes[0]
+                    )
+                    return (out / dp_total(axes)).astype(g.dtype)
 
-            grads = jax.tree.map(sync_leaf, grads)
-        elif len(axes) == 1:
-            grads = coll.allreduce_tree(grads, algo, axes, None, mean=True)
-        else:
-            grads = coll.allreduce_tree(grads, algo, axes, dp_shape, mean=True)
-        loss = jax.lax.pmean(loss, axes)
-        aux = jax.lax.pmean(aux, axes)
+                grads = jax.tree.map(sync_leaf, grads)
+            elif len(axes) == 1:
+                grads = coll.allreduce_tree(grads, algo, axes, None, mean=True)
+            else:
+                grads = coll.allreduce_tree(grads, algo, axes, dp_shape, mean=True)
+            loss = jax.lax.pmean(loss, axes)
+            aux = jax.lax.pmean(aux, axes)
         return grads, loss, aux
 
     def dp_total(axes):
@@ -205,7 +210,8 @@ def make_train_step(cfg: ArchConfig, ocfg: opt.AdamWConfig, options: TrainOption
             check_vma=False,
         )
         grads, loss, aux = grads_fn(params, batch)
-        new_params, new_state, m = opt.apply(ocfg, opt_state, params, grads)
+        with jax.named_scope(OD.OPTIMIZER):
+            new_params, new_state, m = opt.apply(ocfg, opt_state, params, grads)
         return new_params, new_state, {"loss": loss, "aux": aux, **m}
 
     return train_step
