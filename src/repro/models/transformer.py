@@ -6,7 +6,9 @@ Covers the assigned families:
 * encoder-decoder with conv-frontend stub (whisper-tiny)
 
 Layer stacks are parameterized for ``lax.scan`` (params carry a leading L
-dim); remat policy is applied by the training layer.
+dim); remat policy is applied by the training layer.  ``decode_step`` scans
+the layers with the stacked KV cache read in place, not carried, and writes
+the step's new rows into it once, after the scan.
 """
 
 from __future__ import annotations
@@ -311,7 +313,19 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
 
 
 def decode_step(cfg: ArchConfig, params, cache, tokens, positions=None):
-    """One-token decode: tokens (B, 1) -> (logits (B,1,V), new_cache)."""
+    """One-token decode: tokens (B, 1) -> (logits (B,1,V), new_cache).
+
+    The stacked cache is read inside the layer scan but never carried
+    through it or scanned over: each layer's attention reads its layer of
+    ``cache["k"]``/``cache["v"]`` straight from the stacked buffer, with the
+    layer's new row selected in at position ``len``, and the scan returns the
+    new rows, which one ``dynamic_update_slice`` at ``(0, 0, len, 0, 0)``
+    writes after it.  Under donation (``launch/serve.make_step``) that write
+    is in place, so a step reads the cache once and writes only the new rows
+    (though with a positions-minor cache layout, which the TPU compiler picks
+    for 64-wide heads, writing one position touches every tile).  Carrying the cache through the scan, or scanning over it as ``xs``, makes
+    the compiler copy layers or the whole cache between layouts every step.
+    """
     b = tokens.shape[0]
     hd = cfg.kq_head_dim
     h_, kv = cfg.n_heads, cfg.n_kv_heads
@@ -326,9 +340,12 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, positions=None):
         if cfg.rope_type == "learned":
             x = x + lax.dynamic_slice_in_dim(params["pos_embed"], pos_scalar, 1)[None]
 
-    def layer_fn(carry, lp_and_cache):
-        h, li = carry
-        lp, kc, vc, xk, xv = lp_and_cache
+    kc, vc = cache["k"], cache["v"]
+    at_len = (jnp.arange(kc.shape[2]) == pos_scalar)[None, :, None, None]
+    window = cfg.local_window if cfg.family == "vlm" else 0
+
+    def layer_fn(h, lp_and_index):
+        lp, li = lp_and_index
         a = _norm(cfg, h, lp["attn_norm"])
         with jax.named_scope(OD.ATTENTION):
             q = jnp.einsum("bsd,dq->bsq", a, lp["wq"]).reshape(b, 1, h_, hd)
@@ -336,15 +353,17 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, positions=None):
             v = jnp.einsum("bsd,dq->bsq", a, lp["wv"]).reshape(b, 1, kv, hd)
             if cfg.rope_type in ("rope", "mrope"):
                 q, k = _apply_pos(cfg, q, k, positions)
-            kc = lax.dynamic_update_slice_in_dim(kc, k.astype(kc.dtype), pos_scalar, axis=1)
-            vc = lax.dynamic_update_slice_in_dim(vc, v.astype(vc.dtype), pos_scalar, axis=1)
-            o = L.attention_decode(q, kc, vc, pos_scalar + 1,
-                                   window=cfg.local_window if cfg.family == "vlm" else 0)
+            k, v = k.astype(kc.dtype), v.astype(vc.dtype)
+            lk = jnp.where(at_len, k, lax.dynamic_index_in_dim(kc, li, keepdims=False))
+            lv = jnp.where(at_len, v, lax.dynamic_index_in_dim(vc, li, keepdims=False))
+            o = L.attention_decode(q, lk, lv, pos_scalar + 1, window=window)
             attn = jnp.einsum("bsq,qd->bsd", o.reshape(b, 1, h_ * hd), lp["wo"])
         h = h + attn
         if cfg.enc_layers:
             xa = _norm(cfg, h, lp["xattn_norm"])
             with jax.named_scope(OD.ATTENTION):
+                xk = lax.dynamic_index_in_dim(cache["xk"], li, keepdims=False)
+                xv = lax.dynamic_index_in_dim(cache["xv"], li, keepdims=False)
                 qx = jnp.einsum("bsd,dq->bsq", xa, lp["xwq"]).reshape(b, 1, h_, hd)
                 o = L.attention_decode(qx, xk, xv, xk.shape[1])
                 attn = jnp.einsum("bsq,qd->bsd", o.reshape(b, 1, h_ * hd), lp["xwo"])
@@ -355,18 +374,17 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, positions=None):
                 y, _ = moe_lib.moe_apply(m, lp["moe"], cfg.top_k, cfg.capacity_factor)
         else:
             y = _mlp_block(cfg, lp, m)
-        return (h + y, li + 1), (kc, vc)
+        return h + y, (k, v)
 
-    lp = params["layers"]
-    xk = cache.get("xk", jnp.zeros((cfg.n_layers, b, 1, kv, hd), jnp.bfloat16))
-    xv = cache.get("xv", xk)
     with jax.named_scope(OD.LAYERS):
-        (x, _), (new_k, new_v) = lax.scan(
-            layer_fn, (x, 0), (lp, cache["k"], cache["v"], xk, xv)
-        )
+        x, (k_rows, v_rows) = lax.scan(
+            layer_fn, x, (params["layers"], jnp.arange(cfg.n_layers)))
+        with jax.named_scope(OD.ATTENTION):
+            kc = lax.dynamic_update_slice(kc, k_rows, (0, 0, pos_scalar, 0, 0))
+            vc = lax.dynamic_update_slice(vc, v_rows, (0, 0, pos_scalar, 0, 0))
     with jax.named_scope(OD.UNEMBED):
         x = L.apply_norm(x, params["final_norm"], cfg.norm_type)
         unembed = params.get("unembed", params["embed"].T)
         logits = jnp.einsum("bsd,dv->bsv", x, unembed)
-    new_cache = dict(cache, k=new_k, v=new_v, len=pos_scalar + 1)
+    new_cache = dict(cache, k=kc, v=vc, len=pos_scalar + 1)
     return logits, new_cache

@@ -1,13 +1,15 @@
 """Model correctness: SSD vs sequential recurrence, RG-LRU scan vs step,
 decode-vs-forward consistency, MoE no-drop equivalence."""
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs.base import ArchConfig
-from repro.models import get_model
+from repro.models import get_model, transformer
 from repro.models.mamba2 import ssd_chunked
 from repro.models.recurrentgemma import rglru, rglru_step
 
@@ -83,6 +85,48 @@ def test_decode_matches_forward(cfg):
     for t in range(8):
         lg, cache = m.decode_step(cfg, params, cache, toks[:, t : t + 1])
         outs.append(lg[:, 0])
+    dec = jnp.stack(outs, 1)
+    np.testing.assert_allclose(
+        np.asarray(full, np.float32), np.asarray(dec, np.float32), rtol=2e-2, atol=2e-4
+    )
+
+
+DONATED_CASES = CONSISTENCY_CASES + [
+    # the window is as long as the steps: the first position sits on its edge
+    ArchConfig("vlm", "vlm", 2, 64, 4, 2, 128, 256, head_dim=16, rope_type="mrope",
+               mrope_sections=(4, 2, 2), local_window=8),
+    ArchConfig("audio", "audio", 2, 64, 4, 4, 128, 256, head_dim=16, enc_layers=2,
+               enc_seq=16, rope_type="learned", norm_type="layernorm", act="gelu"),
+]
+
+
+@pytest.mark.parametrize("cfg", DONATED_CASES, ids=lambda c: c.name)
+def test_donated_decode_matches_forward(cfg):
+    """The decode step jitted with its cache donated, as ``launch/serve.make_step``
+    runs it, over a cache longer than the steps: each step writes its rows into
+    the buffers of the last, and the logits still match ``forward``."""
+    m = get_model(cfg)
+    params = m.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    toks = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0, cfg.vocab)
+    cache = m.init_cache(cfg, 2, 24, dtype=jnp.float32)
+    extra = {}
+    if cfg.enc_layers:
+        frames = jax.random.normal(jax.random.PRNGKey(2), (2, cfg.enc_seq, cfg.d_model))
+        extra["encoder_frames"] = frames
+        enc = transformer._encoder_forward(cfg, params["encoder"], frames, remat=False)
+        lp = params["layers"]
+        for name in ("k", "v"):
+            x = jnp.einsum("bsd,ldq->lbsq", enc, lp[f"xw{name}"])
+            cache[f"x{name}"] = x.reshape(cache[f"x{name}"].shape)
+    full, _ = m.forward(cfg, params, toks, remat=False, **extra)
+    step = jax.jit(partial(m.decode_step, cfg), donate_argnums=(1,))
+    outs = []
+    for t in range(8):
+        last = cache
+        lg, cache = step(params, cache, toks[:, t : t + 1])
+        assert all(a.is_deleted() for a in jax.tree.leaves(last))
+        outs.append(lg[:, 0])
+    assert int(cache["len"]) == 8
     dec = jnp.stack(outs, 1)
     np.testing.assert_allclose(
         np.asarray(full, np.float32), np.asarray(dec, np.float32), rtol=2e-2, atol=2e-4

@@ -8,7 +8,9 @@ program and that it fits one chip's memory, not that its results are right.
 """
 
 import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -92,6 +94,28 @@ def test_decode_step_fits_one_chip(one_chip):
     compiled = serve.make_step(MINICPM).lower(
         _placed(params, one_chip), _placed(cache, one_chip), tok).compile()
     assert _hbm_bytes(compiled) < HBM_LIMIT
+
+
+def test_decode_step_updates_the_cache_in_place(one_chip):
+    """minicpm-2b at batch 16 and 512 positions: the donated cache is written
+    in place, with no copy or transpose of the stacked cache or of one layer
+    of it, and the step's temporaries stay far below the cache's size."""
+    model = get_model(MINICPM)
+    params = jax.eval_shape(lambda: model.init_params(MINICPM, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: model.init_cache(MINICPM, 16, 512))
+    tok = jax.ShapeDtypeStruct((16, 1), jnp.int32, sharding=one_chip)
+    compiled = serve.make_step(MINICPM).lower(
+        _placed(params, one_chip), _placed(cache, one_chip), tok).compile()
+    stacked = cache["k"].size
+    cache_sized = {stacked, stacked // MINICPM.n_layers}
+    moved = [
+        line.strip()[:160] for line in compiled.as_text().splitlines()
+        if (m := re.search(r"= \w+\[([\d,]*)\]\S* (?:copy|transpose)\(", line))
+        and math.prod(int(d) for d in m.group(1).split(",") if d) in cache_sized
+    ]
+    assert not moved, moved
+    cache_bytes = 2 * stacked * cache["k"].dtype.itemsize  # k and v
+    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes / 4
 
 
 def test_train_step_fits_one_chip(one_chip):
