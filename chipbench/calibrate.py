@@ -30,11 +30,11 @@ import numpy as np
 import run as harness  # chipbench/run.py, which puts the repo on the path
 
 from chipbench import common, compare
-from chipbench.reference import serve_ref, train_ref
 
 
 def readings(ctx) -> list:
-    """The control's and the faults' numbers for the run ``ctx`` checked."""
+    """The control's and the faults' numbers for the run ``ctx`` checked,
+    from the reference of the cell's model family."""
     import jax.numpy as jnp
 
     out = []
@@ -45,16 +45,18 @@ def readings(ctx) -> list:
         if chips > 1:
             runs["fault_no_exchange"] = dict(fault="no_exchange", chips=chips)
         for name, kw in runs.items():
-            got = train_ref.run(ctx.config, ctx.traffic, ctx.seed, device=ctx.devices[0], **kw)
+            got = ctx.family.train_reference(ctx.config, ctx.traffic, ctx.seed,
+                                             device=ctx.devices[0], **kw)
             out.append((name, compare.train_numbers(got, ctx.reference)))
     else:
         dtype = jnp.dtype(ctx.config["torch_dtype"])
-        gaps = serve_ref.gaps(ctx.config, ctx.seed, dtype, ctx.sampled, control=True,
-                              device=ctx.devices[0])
+        gaps = ctx.family.serve_gaps(ctx.config, ctx.seed, dtype, ctx.sampled, control=True,
+                                     device=ctx.devices[0])
         out.append(("control_fp8", {"served_gap": compare.served_gap(gaps)}))
         vocab = ctx.config["vocab_size"]
         altered = [(p, np.concatenate([s[:-1], (s[-1:] + 1) % vocab])) for p, s in ctx.sampled]
-        gaps = serve_ref.gaps(ctx.config, ctx.seed, dtype, altered, device=ctx.devices[0])
+        gaps = ctx.family.serve_gaps(ctx.config, ctx.seed, dtype, altered,
+                                     device=ctx.devices[0])
         out.append(("fault_token_altered", {"served_gap": compare.served_gap(gaps)}))
     return out
 

@@ -2,13 +2,18 @@
 the host clock and the device's peak memory.
 
 Nothing here imports the program (``repro``) except :func:`arch_config`,
-which builds the program's config object from a configuration file.
+which builds the program's config object from a configuration file.  What
+belongs to one model family (its reference, operation counts and smoke size)
+is in ``chipbench/families/<family>.py``, found by :func:`family` from the
+family the configuration's ``program`` group names.
 """
 
 from __future__ import annotations
 
+import importlib.util
 import json
 import pathlib
+import sys
 import time
 
 import numpy as np
@@ -47,6 +52,37 @@ def cell(spec: dict, root: pathlib.Path, name: str) -> tuple[dict, dict, dict]:
     if not traffic_path.is_file():
         raise SpecError(f"no traffic file {traffic_path}")
     return w, config, json.loads(traffic_path.read_text())
+
+
+def family(root: pathlib.Path, config: dict):
+    """The module of ``config``'s model family: ``chipbench/families/<family>.py``
+    under ``root``, for the family its ``program`` group names."""
+    name = config["program"]["family"]
+    path = root / "chipbench" / "families" / f"{name}.py"
+    if not path.is_file():
+        raise SpecError(f"configuration {config['name']!r} names family {name!r}: no {path}")
+    return load_module(path, f"chipbench_family_{name}")
+
+
+_LOADED: dict = {}  # resolved path -> module
+
+
+def load_module(path: pathlib.Path, name: str):
+    """The Python file ``path`` as the module ``name``: a file of a checkout,
+    which need not be on the import path.  Each file runs once a process,
+    and is in ``sys.modules`` while it runs (a dataclass needs that)."""
+    path = pathlib.Path(path).resolve()
+    if path not in _LOADED:
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[name]
+            raise
+        _LOADED[path] = mod
+    return _LOADED[path]
 
 
 def end_to_end_names(spec: dict, workload: str) -> list[str]:

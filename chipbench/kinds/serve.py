@@ -15,8 +15,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from chipbench import common, compare, flops
-from chipbench.reference import serve_ref
+from chipbench import common, compare
 from chipbench.reference import weights as W
 
 PROMPT_STREAM = 1 << 30  # prompt rows of wave i come from index PROMPT_STREAM + i
@@ -49,8 +48,8 @@ def run(ctx) -> None:
     from repro.launch import serve
     from repro.models import get_model
 
-    mix, config = ctx.traffic, ctx.config
-    cfg = common.arch_config(config)
+    mix, config, family = ctx.traffic, ctx.config, ctx.family
+    cfg = family.arch_config(config)
     model = get_model(cfg)
     batch, shapes = mix["batch"], [(s["prompt"], s["output"]) for s in mix["waves"]]
     dtype = jnp.dtype(config["torch_dtype"])
@@ -97,14 +96,14 @@ def run(ctx) -> None:
         e2e={"serve_tokens_per_s": served / window_s,
              "request_latency_p95_ms": 1e3 * float(np.percentile(latencies, 95))},
         layer_inputs={"waves": [(p, o) for p, o, *_ in waves],
-                      "flops": sum(flops.wave_flops(config, batch, p, o) for p, o, *_ in waves),
-                      "bytes": sum(flops.wave_bytes(config, batch, p, o) for p, o, *_ in waves)})
+                      "flops": sum(family.wave_flops(config, batch, p, o) for p, o, *_ in waves),
+                      "bytes": sum(family.wave_bytes(config, batch, p, o) for p, o, *_ in waves)})
     ctx.read_memory()
     del params, compiled, tap
     jax.clear_caches()
 
     requests = ctx.sampled = sample(waves, ctx.seed, mix["check_requests"])
-    gaps = serve_ref.gaps(config, ctx.seed, dtype, requests, device=ctx.devices[0])
+    gaps = family.serve_gaps(config, ctx.seed, dtype, requests, device=ctx.devices[0])
     ctx.check("served_gap", compare.served_gap(gaps), mix["limits"]["served_gap"])
     ctx.check("compiles_in_window", ctx.window_lowerings, 0)
 

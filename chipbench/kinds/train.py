@@ -11,8 +11,7 @@ from __future__ import annotations
 
 import math
 
-from chipbench import common, compare, flops
-from chipbench.reference import train_ref
+from chipbench import common, compare
 from chipbench.reference import weights as W
 
 CHECK_STEPS = 3
@@ -28,8 +27,8 @@ def run(ctx) -> None:
     from repro.models import get_model
     from repro.train import optimizer as opt_lib
 
-    job, config = ctx.traffic, ctx.config
-    cfg = common.arch_config(config, schedule=job["lr_schedule"])
+    job, config, family = ctx.traffic, ctx.config, ctx.family
+    cfg = family.arch_config(config, schedule=job["lr_schedule"])
     devices = ctx.devices
     mesh = jax.make_mesh((len(devices),), ("data",), axis_types=(AxisType.Auto,),
                          devices=devices)
@@ -97,14 +96,15 @@ def run(ctx) -> None:
     ctx.report(attempted=n, failed=failed,
                e2e={"train_tokens_per_s": tokens / window_s},
                layer_inputs={"steps": n,
-                             "flops_per_step": flops.train_step_flops(config, batch, seq)})
+                             "flops_per_step": family.train_step_flops(config, batch, seq)})
     ctx.read_memory()
     loop.close()
     del loop, step, params, opt_state, metrics
     jax.clear_caches()
 
-    ref = train_ref.run(config, job, ctx.seed, steps=CHECK_STEPS, dtype=jnp.dtype(job["dtype"]),
-                        chips=len(devices), device=devices[0])
+    ref = family.train_reference(config, job, ctx.seed, steps=CHECK_STEPS,
+                                 dtype=jnp.dtype(job["dtype"]), chips=len(devices),
+                                 device=devices[0])
     ctx.reference = ref
     got = {"losses": losses, "grad_norms": first_grad, "change_norms": change}
     for name, value in compare.train_numbers(got, ref).items():

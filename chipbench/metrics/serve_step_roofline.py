@@ -1,8 +1,8 @@
 """The decode step's (``serve_step``) share of its HBM roofline: the least bytes of every
 decode step of the window (bf16 weights, the valid cache rows read, one row
 written) over the device time of the decode program (``serve_step``) times
-the chip's HBM bandwidth.  The rest of ``max_len`` read and the second copy
-of the cache the layer scan writes are not counted as needed."""
+the chip's HBM bandwidth.  The rest of ``max_len`` read is not counted as
+needed."""
 
 PROGRAM = r"serve_step"
 

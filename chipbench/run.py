@@ -3,9 +3,11 @@
 
   python chipbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-The cell names a configuration (``chipbench/configs/<name>.json``) and a
-traffic file (``chipbench/traffic/<name>.json``) whose ``kind`` picks the
-runner (``chipbench/kinds/<kind>.py``).  Set-up makes the weights on the
+The cell names a configuration (``chipbench/configs/<name>.json``), whose
+``program.family`` picks the model family's reference and counts
+(``chipbench/families/<family>.py``), and a traffic file
+(``chipbench/traffic/<name>.json``) whose ``kind`` picks the runner
+(``chipbench/kinds/<kind>.py``).  Set-up makes the weights on the
 device from ``--seed`` and warms every shape the window uses; the window
 then runs for ``--seconds``; the reference then checks what the window's
 program produced.  With ``--trace 0`` the result carries the end-to-end
@@ -29,7 +31,6 @@ import argparse  # noqa: E402
 import contextlib  # noqa: E402
 import gc  # noqa: E402
 import importlib  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import pathlib  # noqa: E402
@@ -50,10 +51,10 @@ class NoChip(Exception):
 class Ctx:
     """One run: what the runner reads, and what it reports."""
 
-    def __init__(self, *, root, spec, workload, config, traffic, seed, seconds,
+    def __init__(self, *, root, spec, workload, config, family, traffic, seed, seconds,
                  trace, devices, started):
         self.root, self.spec, self.workload = root, spec, workload
-        self.config, self.traffic = config, traffic
+        self.config, self.family, self.traffic = config, family, traffic
         self.seed, self.seconds, self.trace = seed, seconds, trace
         self.devices, self.started = devices, started
         self.setup_s = None
@@ -123,11 +124,8 @@ class Ctx:
 
 
 def load_metric(root: pathlib.Path, name: str):
-    path = root / "chipbench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"chipbench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return common.load_module(root / "chipbench" / "metrics" / f"{name}.py",
+                              f"chipbench_metric_{name}")
 
 
 def per_layer(ctx: Ctx, reduced) -> dict:
@@ -168,10 +166,11 @@ def execute(root: pathlib.Path, workload: str, seed: int, seconds: float, trace:
     """Set-up, window and check of one cell -> the run's ``Ctx``."""
     spec = common.load_spec(root)
     entry, config, traffic = common.cell(spec, root, workload)
+    family = common.family(root, config)
     enable_compile_cache(root)
     devices = devices_for(entry["chips"], require_chip)
-    ctx = Ctx(root=root, spec=spec, workload=entry, config=config, traffic=traffic,
-              seed=seed, seconds=seconds, trace=trace, devices=devices,
+    ctx = Ctx(root=root, spec=spec, workload=entry, config=config, family=family,
+              traffic=traffic, seed=seed, seconds=seconds, trace=trace, devices=devices,
               started=STARTED if started is None else started)
     importlib.import_module(f"chipbench.kinds.{traffic['kind']}").run(ctx)
     return ctx

@@ -1,9 +1,11 @@
 """A copy of the benchmark at smoke size, for tests on the CPU.
 
-Every configuration keeps its layout (heads per kv head, tying, theta) at
-tiny widths; every traffic file keeps its kind, sync and limits with tiny
+Every configuration is cut by its model family's ``smoke_config`` (the dense
+decoder keeps its layout, heads per kv head, tying and theta, at tiny
+widths); every traffic file keeps its kind, sync and limits with tiny
 batches and lengths.  The copy is a root of its own: ``BENCHMARK.json``,
-``chipbench/configs``, ``chipbench/traffic`` and ``chipbench/metrics``.
+``chipbench/configs``, ``chipbench/traffic``, ``chipbench/metrics`` and
+``chipbench/families``.
 """
 
 from __future__ import annotations
@@ -21,16 +23,14 @@ for p in (str(REPO), str(REPO / "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-SMALL = {"hidden_size": 64, "intermediate_size": 128, "head_dim": 16,
-         "vocab_size": 512, "num_hidden_layers": 2}
 SEED = 2**31 + 11  # past 32 signed bits, as the driver's seeds are
 
 
-def small_config(config: dict) -> dict:
-    c = dict(config, **SMALL)
-    groups = config["num_attention_heads"] // config["num_key_value_heads"]
-    c["num_attention_heads"], c["num_key_value_heads"] = 4, 4 // min(groups, 4)
-    return c
+def small_config(config: dict, root: pathlib.Path = REPO) -> dict:
+    """``config`` at smoke size, by the rule of its family under ``root``."""
+    from chipbench import common
+
+    return common.family(root, config).smoke_config(config)
 
 
 def small_traffic(traffic: dict) -> dict:
@@ -44,17 +44,19 @@ def small_traffic(traffic: dict) -> dict:
     return t
 
 
-def build(root: pathlib.Path) -> pathlib.Path:
-    """The smoke copy under ``root``."""
-    spec = json.loads((REPO / "BENCHMARK.json").read_text())
+def build(root: pathlib.Path, src: pathlib.Path = REPO) -> pathlib.Path:
+    """The smoke copy under ``root`` of the benchmark under ``src``."""
+    spec = json.loads((src / "BENCHMARK.json").read_text())
     (root / "chipbench" / "configs").mkdir(parents=True)
     (root / "chipbench" / "traffic").mkdir()
-    shutil.copytree(REPO / "chipbench" / "metrics", root / "chipbench" / "metrics")
+    for d in ("metrics", "families"):
+        shutil.copytree(src / "chipbench" / d, root / "chipbench" / d,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     for c in spec["configs"]:
-        config = json.loads((REPO / c["file"]).read_text())
-        (root / c["file"]).write_text(json.dumps(small_config(config)))
+        config = json.loads((src / c["file"]).read_text())
+        (root / c["file"]).write_text(json.dumps(small_config(config, src)))
     for w in spec["workloads"]:
-        t = json.loads((REPO / "chipbench" / "traffic" / f"{w['traffic']}.json").read_text())
+        t = json.loads((src / "chipbench" / "traffic" / f"{w['traffic']}.json").read_text())
         (root / "chipbench" / "traffic" / f"{w['traffic']}.json").write_text(
             json.dumps(small_traffic(t)))
     (root / "BENCHMARK.json").write_text(json.dumps(spec))

@@ -5,7 +5,8 @@ Each fault is planted underneath the timed path (the program's optimizer,
 loss, gradient sync, decode step or ``generate``), the rest of a run is
 driven as on the chip but for the look for a chip, and ``correct`` must come
 out false.  The controls are the reference computed one precision below the
-configuration's, read against the cell's own limits.
+configuration's, read against the cell's own limits: each from the
+reference of the cell's model family.
 """
 
 import json
@@ -15,8 +16,7 @@ import numpy as np
 import pytest
 
 import chipbench_smoke as smoke
-from chipbench import compare
-from chipbench.reference import serve_ref, train_ref
+from chipbench import common, compare
 
 SPEC = json.loads((smoke.REPO / "BENCHMARK.json").read_text())
 
@@ -115,14 +115,20 @@ def test_four_chip_cell_is_correct_and_catches_a_missing_exchange(tmp_path, cell
     assert not broken["correct"], broken["checks"]
 
 
-@pytest.mark.parametrize("cell", TRAIN_CELLS)
-def test_training_control_in_bfloat16_is_not_correct(cell):
+def _small_config(cell):
     w = next(w for w in SPEC["workloads"] if w["name"] == cell)
     config = smoke.small_config(json.loads(
         (smoke.REPO / "chipbench" / "configs" / f"{w['config']}.json").read_text()))
+    return w, config, common.family(smoke.REPO, config)
+
+
+@pytest.mark.parametrize("cell", TRAIN_CELLS)
+def test_training_control_in_bfloat16_is_not_correct(cell):
+    w, config, family = _small_config(cell)
     job = smoke.small_traffic(_traffic(w["traffic"]))
-    ref = train_ref.run(config, job, smoke.SEED)
-    control = train_ref.run(config, job, smoke.SEED, dtype=jnp.bfloat16, precision="default")
+    ref = family.train_reference(config, job, smoke.SEED)
+    control = family.train_reference(config, job, smoke.SEED, dtype=jnp.bfloat16,
+                                     precision="default")
     numbers = compare.train_numbers(control, ref)
     assert any(v > job["limits"][k] for k, v in numbers.items()), numbers
 
@@ -131,11 +137,10 @@ def test_training_control_in_bfloat16_is_not_correct(cell):
 def test_serving_control_in_float8_is_not_correct(cell):
     """The control's first choices read against the reference's logits, at
     every position of a few drawn sequences."""
-    w = next(w for w in SPEC["workloads"] if w["name"] == cell)
-    config = smoke.small_config(json.loads(
-        (smoke.REPO / "chipbench" / "configs" / f"{w['config']}.json").read_text()))
+    w, config, family = _small_config(cell)
     limit = _traffic(w["traffic"])["limits"]["served_gap"]
     rng = np.random.default_rng(0)
-    requests = [(rng.integers(0, 512, 16), rng.integers(0, 512, 48)) for _ in range(4)]
-    control = serve_ref.gaps(config, smoke.SEED, jnp.bfloat16, requests, control=True)
+    vocab = config["vocab_size"]
+    requests = [(rng.integers(0, vocab, 16), rng.integers(0, vocab, 48)) for _ in range(4)]
+    control = family.serve_gaps(config, smoke.SEED, jnp.bfloat16, requests, control=True)
     assert compare.served_gap(control) > limit

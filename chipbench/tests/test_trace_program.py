@@ -201,6 +201,34 @@ def test_scopes_take_the_self_time_of_the_window_steps():
     assert r.unscoped["jit_concatenate"] == pytest.approx({"<concatenate.3>": 0.0017})
 
 
+def test_any_named_scope_is_read_under_its_op_names():
+    """A scope nested under ``mlp`` that ``SCOPES`` does not know is read by
+    ``per_step_ms_under``, with an op the compiler added inside it; what
+    ``per_step_ms`` reads does not change."""
+    experts = "jit(serve_step)/while/body/closed_call/mlp/experts/gate/dot_general"
+    ops = {"jit_serve_step(7)": dict(SERVE_OPS["jit_serve_step(7)"], **{"fusion.2": experts})}
+    planes = serving()
+    planes[1].lines[0].events.append(_ms("%copy.9 = bf16[2] copy(...)", 3.0, 3.5))
+    r = trace_program.read(planes, ops)
+    before = trace_program.read(serving(), SERVE_OPS)
+    for scopes in (["mlp"], ["attention"], ["layers"], ["mlp", "attention"]):
+        assert r.per_step_ms("serve_step", scopes) == pytest.approx(
+            before.per_step_ms("serve_step", scopes))
+    for program, by_scope in before.scope_s.items():
+        assert r.scope_s[program] == pytest.approx(by_scope)
+    # fusion.2's self time in two steps, the copy inside it among it
+    assert r.op_s["jit_serve_step"][experts] == pytest.approx(0.0025 + 0.0028)
+    for names in (["experts"], ["gate"], ["mlp"], ["mlp", "experts"]):
+        assert r.per_step_ms_under("serve_step", names) == pytest.approx(5.3 / 3)
+    assert r.per_step_ms_under("serve_step", ["experts", "attention"]) == pytest.approx(
+        (5.3 + 36.7) / 3)
+    # the while's own time and the copy-done it runs: as per_step_ms reads them
+    assert r.per_step_ms_under("serve_step", ["layers"]) == pytest.approx(0.4 / 3)
+    # a name a component merely contains is not that component
+    assert r.per_step_ms_under("serve_step", ["expert"]) is None
+    assert r.per_step_ms_under("concatenate", ["experts"]) is None
+
+
 def _read_metrics(monkeypatch, reading, names=NEW_METRICS):
     monkeypatch.setattr(trace_program, "of", lambda reduced: reading)
     peaks = common.peaks("TPU v5 lite")

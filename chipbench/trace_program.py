@@ -33,7 +33,10 @@ What a traced run's metrics read (``of``):
   ``XLA Modules`` events give it); an instruction's ``op_name`` names the
   innermost scope it was emitted under.  An instruction the compiler added
   has none: it takes the op_name of the computations it calls or of the
-  operands it reads, else the scope of the op it runs inside.
+  operands it reads, else the op_name of the op it runs inside.  Self time
+  is kept by op_name, so that any named scope, nested or not, can be read
+  (``per_step_ms_under``), and summed from it by scope (``SCOPES``, the
+  innermost).
 * **Clock offset.**  On a TPU v5e a chip's events are stamped earlier than
   the host events that issued them (on the recorded fixture every execution
   starts 1.256-1.280 ms before its enqueue).  Each chip's offset is the
@@ -120,6 +123,7 @@ class Reading:
     steps: dict  # program -> executions starting in the window (summed over chips)
     scope_s: dict  # program -> {scope ("" for none): self seconds, summed over chips}
     unscoped: dict  # program -> {op_name (or <instruction>): self seconds}
+    op_s: dict  # program -> {op_name, inherited (or <instruction>): self seconds}
     clock_offset_s: dict  # chip -> offset (chips with a paired execution)
     idle_gaps: list | None = None  # [[label, seconds], ...]: trace_reduce's gaps, causal labels
 
@@ -152,15 +156,29 @@ class Reading:
         """Device self time under ``scopes`` per execution of the programs
         matching ``pattern``, per chip, in ms; None where no op of theirs is
         under any of ``scopes``."""
+        return self._per_step_ms(pattern, self.scope_s, lambda scope: scope in scopes)
+
+    def per_step_ms_under(self, pattern: str, names) -> float | None:
+        """Device self time of the ops whose op_name has a path component in
+        ``names`` (any named scope, nested or not, in ``SCOPES`` or not) per
+        execution of the programs matching ``pattern``, per chip, in ms; None
+        where no op of theirs is under any of ``names``."""
+        under = re.compile(r"(?:^|[/(])(?:%s)(?=[)/]|$)" % "|".join(map(re.escape, names)))
+        return self._per_step_ms(pattern, self.op_s, under.search)
+
+    def _per_step_ms(self, pattern, table, keep) -> float | None:
+        """Self time of the keys of ``table`` (program -> {key: seconds})
+        that ``keep`` takes, per execution of the programs matching
+        ``pattern``, in ms."""
         n = total = 0.0
         found = False
-        for program, by_scope in self.scope_s.items():
+        for program, by_key in table.items():
             if re.search(pattern, program):
                 n += self.steps[program]
-                for s in scopes:
-                    if s in by_scope:
+                for key, t in by_key.items():
+                    if keep(key):
                         found = True
-                        total += by_scope[s]
+                        total += t
         if not found or n == 0:
             return None
         return 1e3 * total / n
@@ -272,9 +290,10 @@ def read(planes, op_names: dict | None = None, *, gaps: bool = False) -> Reading
     for x in executions:
         if x.enqueue is not None:
             offsets[x.chip] = max(offsets.get(x.chip, x.enqueue - x.start), x.enqueue - x.start)
-    steps, scope_s, unscoped = _scopes(devices, executions, offsets, window, op_names)
+    steps, op_s, unscoped = _scopes(devices, executions, offsets, window, op_names)
     reading = Reading(window=window, chips=len(devices), executions=executions, spans=spans,
-                      steps=steps, scope_s=scope_s, unscoped=unscoped, clock_offset_s=offsets)
+                      steps=steps, scope_s=_by_scope(op_s), unscoped=unscoped, op_s=op_s,
+                      clock_offset_s=offsets)
     if gaps:
         found = _gaps(devices, window)
         reading.idle_gaps = [[_gap_label(s, e, chip, executions, offsets, spans), e - s]
@@ -437,8 +456,8 @@ def _gap_label(s, e, chip, executions, offsets, spans) -> str:
 
 def _scopes(devices, executions, offsets, window, op_names):
     """Op self time of the executions that start in the window, by program
-    and scope."""
-    steps, scope_s, unscoped = {}, {}, {}
+    and op_name (inherited), and of those under no scope by their own."""
+    steps, op_s, unscoped = {}, {}, {}
     for chip, dev in devices.items():
         off = offsets.get(chip, 0.0)
         mods = sorted((x for x in executions if x.chip == chip), key=lambda x: x.start)
@@ -449,7 +468,7 @@ def _scopes(devices, executions, offsets, window, op_names):
         events = dev.get(trace_reduce.OPS_LINE, [])
         ops = [(ev.start_ns * 1e-9, (ev.start_ns + ev.duration_ns) * 1e-9) for ev in events]
         own_s, parent, order = _self_times(ops)
-        scope_at = [""] * len(ops)  # parents come first in start order
+        op_at = [""] * len(ops)  # parents come first in start order
         names: dict = {}
         for i in order:
             ev, s = events[i], ops[i][0]
@@ -462,18 +481,36 @@ def _scopes(devices, executions, offsets, window, op_names):
                 instr = names[ev.name] = trace_reduce.op_name(ev.name)
             op = op_names.get(x.module, {}).get(instr, "")
             # an op the compiler added (no op_name) inside a loop or call
-            # belongs to the scope of the op it runs in
-            scope = scope_at[i] = scope_of(op) if op or parent[i] < 0 else scope_at[parent[i]]
+            # takes the op_name, and so the scope, of the op it runs in
+            named = op_at[i] = op if op or parent[i] < 0 else op_at[parent[i]]
             if not window[0] <= x.start + off < window[1]:
                 continue
             own = own_s[i]
-            by = scope_s.setdefault(x.program, {})
-            by[scope] = by.get(scope, 0.0) + own
-            if not scope:
+            by_op = op_s.setdefault(x.program, {})
+            key = named or f"<{instr}>"
+            by_op[key] = by_op.get(key, 0.0) + own
+            if not _scope_of_key(key):
                 u = unscoped.setdefault(x.program, {})
                 key = op or f"<{instr}>"
                 u[key] = u.get(key, 0.0) + own
-    return steps, scope_s, unscoped
+    return steps, op_s, unscoped
+
+
+@functools.lru_cache(maxsize=None)
+def _scope_of_key(key: str) -> str:
+    """The scope of an ``op_s`` key ("" for an ``<instruction>``)."""
+    return "" if key.startswith("<") else scope_of(key)
+
+
+def _by_scope(op_s: dict) -> dict:
+    """``op_s`` summed by scope: program -> {scope ("" for none): seconds}."""
+    scope_s: dict = {}
+    for program, by_op in op_s.items():
+        by = scope_s[program] = {}
+        for op, t in by_op.items():
+            scope = _scope_of_key(op)
+            by[scope] = by.get(scope, 0.0) + t
+    return scope_s
 
 
 # ---------------------------------------------------------------------------
