@@ -20,10 +20,24 @@ class ArchConfig:
     vocab: int
     head_dim: int = 0  # 0 -> d_model // n_heads
     # -- MoE --
-    n_experts: int = 0
+    n_experts: int = 0  # the router's width: the experts every token is routed over
     top_k: int = 0
-    capacity_factor: float = 1.25
-    moe_mode: str = "tp"  # tp: expert-tensor-parallel | ep: all_to_all expert parallel
+    capacity_factor: float = 1.25  # the ep / gshard dispatch only; tp is dropless
+    moe_mode: str = "tp"  # tp: dropless, held experts | ep: all_to_all | gshard: einsum
+    experts_held: int = 0  # experts this chip computes (0: all n_experts) ...
+    expert_first: int = 0  # ... from this index on; the rest live on other chips
+    router: str = "softmax"  # softmax (top-k of the softmax) | sigmoid (DeepSeek-V3)
+    routed_scale: float = 1.0  # the gates' scale after normalization (sigmoid router)
+    moe_aux_weight: float = 0.01  # weight of the balance loss in the training loss
+    bias_rate: float = 0.0  # sigmoid router: the correction bias's step per train step
+    shared_d_ff: int = 0  # width of the shared experts' MLP, which every token passes
+    n_dense_layers: int = 0  # leading layers with a dense MLP of width dense_d_ff
+    dense_d_ff: int = 0
+    # -- multi-head latent attention (DeepSeek-V2/V3; kv_lora_rank 0: off) --
+    kv_lora_rank: int = 0  # width of the latent c_kv that keys and values come from
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0  # the rotary part of q.k, its key shared by all heads
+    v_head_dim: int = 0
     # -- SSM (mamba2 / SSD) --
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -57,6 +71,10 @@ class ArchConfig:
         return self.head_dim or (self.d_model // max(1, self.n_heads))
 
     @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.n_experts
+
+    @property
     def quadratic_attention(self) -> bool:
         """True if the arch has unbounded full attention (skips long_500k)."""
         if self.family == "ssm":
@@ -71,12 +89,19 @@ class ArchConfig:
         d, f, L, v = self.d_model, self.d_ff, self.n_layers, self.vocab
         hd = self.kq_head_dim
         attn = d * (self.n_heads * hd) + 2 * d * (self.n_kv_heads * hd) + (self.n_heads * hd) * d
+        if self.kv_lora_rank:
+            r, nope, rope, vd = (self.kv_lora_rank, self.qk_nope_head_dim,
+                                 self.qk_rope_head_dim, self.v_head_dim)
+            attn = (d * self.n_heads * (nope + rope) + d * (r + rope)
+                    + r * self.n_heads * (nope + vd) + self.n_heads * vd * d)
         if self.family == "ssm":
             di = self.ssm_expand * d
             per_layer = d * 2 * di + di * d + di * (2 * self.ssm_state) + di
         elif self.family == "moe":
-            ff = 3 * d * f * self.n_experts + d * self.n_experts
+            ff = 3 * d * (f * self.held_experts + self.shared_d_ff) + d * self.n_experts
+            dense = self.n_dense_layers * (3 * d * self.dense_d_ff - ff)  # in place of experts
             per_layer = attn + ff
+            return v * d * (1 if self.tie_embeddings else 2) + L * per_layer + dense
         elif self.family == "hybrid":
             n_attn = L // max(1, self.attention_period)
             di = d  # rnn width ~ d_model
@@ -98,8 +123,9 @@ class ArchConfig:
         """Active parameters per token (MoE: only top-k experts)."""
         if self.family != "moe":
             return self.param_count
-        d, f, L = self.d_model, self.d_ff, self.n_layers
-        inactive = 3 * d * f * (self.n_experts - self.top_k) * L
+        d, f = self.d_model, self.d_ff
+        inactive = (3 * d * f * (self.held_experts - self.top_k)
+                    * (self.n_layers - self.n_dense_layers))
         return int(self.param_count - inactive)
 
 

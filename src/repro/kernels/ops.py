@@ -15,6 +15,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
 from repro.kernels import flash_attention as fa
 from repro.kernels import ref
 from repro.kernels import rmsnorm as rms
@@ -49,3 +51,12 @@ flash_attention.defvjp(_fwd, _bwd)
 
 def rmsnorm(x, gamma, eps=1e-6):
     return rms.rmsnorm(x, gamma, eps, interpret=not _on_tpu())
+
+
+def grouped_matmul(lhs, rhs, group_sizes, tiling):
+    """Rows of ``lhs`` (M, K) in consecutive groups of ``group_sizes``, each
+    times its own matrix of ``rhs`` (G, K, N): megablox's ``gmm``, with its
+    backward.  Only the first G groups are computed; rows past them (further
+    groups, padding) come out 0.  ``tiling(m, k, n)`` gives the tiles."""
+    return megablox.gmm(lhs, rhs, group_sizes, lhs.dtype, tiling, None, None, False,
+                        not _on_tpu())

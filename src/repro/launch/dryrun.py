@@ -131,7 +131,8 @@ def build_cell(arch: str, shape_name: str, mesh, multi_pod: bool, options, smoke
         train_step = steps_lib.make_train_step(cfg, ocfg, options, policy, mesh,
                                                act_specs=act_specs)
         opt_abs = jax.eval_shape(opt_lib.init, params_abs)
-        ospecs = opt_lib.AdamWState(step=P(), m=pspecs, v=pspecs)
+        ospecs = opt_lib.AdamWState(step=P(), m=pspecs, v=pspecs,
+                                    buffers=jax.tree.map(lambda _: P(), opt_abs.buffers))
         oshard = jax.tree.map(
             lambda s: NamedSharding(mesh, s), ospecs,
             is_leaf=lambda x: isinstance(x, P),

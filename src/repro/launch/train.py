@@ -137,9 +137,13 @@ def main():
                 stop=args.steps, seq=args.seq, batch=args.batch,
                 seed=args.seed):
             if step % 10 == 0 or step == args.steps:
+                # experts' imbalance: the busiest expert's pairs over the mean
+                load = metrics.get("load")
+                imbalance = ("" if load is None else
+                             f"load max/mean {float(load.max() / load.mean()):.2f} ")
                 print(f"[train] step {step:4d} loss {float(metrics['loss']):.4f} "
                       f"lr {float(metrics['lr']):.2e} "
-                      f"gnorm {float(metrics['grad_norm']):.2f} "
+                      f"gnorm {float(metrics['grad_norm']):.2f} {imbalance}"
                       f"({(time.time() - t0):.1f}s)")
             if args.checkpoint_dir and step % args.checkpoint_every == 0:
                 ckpt_lib.save_step(args.checkpoint_dir,

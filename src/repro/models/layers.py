@@ -212,64 +212,70 @@ def attention_chunked(
 ) -> jax.Array:
     """Online-softmax (flash-style) attention in pure jnp.
 
-    Scans over KV chunks keeping running (max, sum, acc) — memory O(Sq·chunk)
-    instead of O(Sq·Sk).  This is the CPU/compile-safe long-sequence path;
-    the Pallas kernel (repro.kernels.flash_attention) is the TPU-target twin.
+    Queries go in blocks of ``chunk`` rows, and each block scans over the KV
+    chunks it can see (causal: up to its last row; window: from its first
+    row's window on), keeping running (max, sum, acc): memory O(chunk²) a
+    step instead of O(Sq·Sk), and no work on a chunk a block cannot see.
+    The backward recomputes each chunk's scores from the carries rather than
+    keeping them.  v may be narrower than q and k (latent attention).  This
+    is the CPU/compile-safe long-sequence path; the Pallas kernel
+    (repro.kernels.flash_attention) is the TPU-target twin.
     """
     b, sq, h, d = q.shape
+    dv = v.shape[-1]
     kv = k.shape[2]
-    sk = k.shape[1]
-    if sk % chunk:
-        pad = (-sk) % chunk
+    kvalid = k.shape[1]
+    pad = (-kvalid) % chunk
+    if pad:
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        kvalid = sk
-        sk = k.shape[1]
-    else:
-        kvalid = sk
     k = _repeat_kv(k, h // kv)
     v = _repeat_kv(v, h // kv)
-    n_chunks = sk // chunk
-    kc = k.reshape(b, n_chunks, chunk, h, d)
-    vc = v.reshape(b, n_chunks, chunk, h, d)
+    n_chunks = k.shape[1] // chunk
+    kc = jnp.moveaxis(k.reshape(b, n_chunks, chunk, h, d), 1, 0)
+    vc = jnp.moveaxis(v.reshape(b, n_chunks, chunk, h, dv), 1, 0)
     qf = (q / math.sqrt(d)).astype(q.dtype)
-    qpos = jnp.arange(sq)
 
-    def body(carry, inp):
-        m, s, acc = carry
-        kb, vb, ci = inp
-        scores = jnp.einsum("bqhd,bkhd->bhqk", qf, kb).astype(jnp.float32)
-        kpos = ci * chunk + jnp.arange(chunk)
-        mask = kpos[None, :] < kvalid
-        if causal:
-            mask &= kpos[None, :] <= qpos[:, None]
-        if window:
-            mask &= kpos[None, :] > (qpos[:, None] - window)
-        scores = jnp.where(mask[None, None], scores, NEG_INF)
-        m_new = jnp.maximum(m, scores.max(-1))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(scores - m_new[..., None])
-        s_new = s * alpha + p.sum(-1)
-        acc_new = acc * alpha[..., None] + jnp.einsum(
-            "bhqk,bkhd->bhqd", p.astype(q.dtype), vb
-        ).astype(jnp.float32)
-        return (m_new, s_new, acc_new), None
+    def rows(lo, hi):
+        """Query rows [lo, hi) -> (B, hi - lo, H, dv) over the chunks they see."""
+        qb, qpos = qf[:, lo:hi], jnp.arange(lo, hi)
+        first = max(0, lo - window + 1) // chunk if window else 0
+        last = -(-min(kvalid, hi) // chunk) if causal else n_chunks
 
-    m0 = jnp.full((b, h, sq), NEG_INF, jnp.float32)
-    s0 = jnp.zeros((b, h, sq), jnp.float32)
-    a0 = jnp.zeros((b, h, sq, d), jnp.float32)
-    (m, s, acc), _ = lax.scan(
-        body,
-        (m0, s0, a0),
-        (
-            jnp.moveaxis(kc, 1, 0),
-            jnp.moveaxis(vc, 1, 0),
-            jnp.arange(n_chunks),
-        ),
-        unroll=scan_unroll(n_chunks),
-    )
-    out = acc / jnp.maximum(s, 1e-30)[..., None]
-    return jnp.moveaxis(out, 1, 2).astype(q.dtype)  # (B,Sq,H,D)
+        def body(carry, inp):
+            m, s, acc = carry
+            kb, vb, ci = inp
+            scores = jnp.einsum("bqhd,bkhd->bhqk", qb, kb).astype(jnp.float32)
+            kpos = ci * chunk + jnp.arange(chunk)
+            mask = kpos[None, :] < kvalid
+            if causal:
+                mask &= kpos[None, :] <= qpos[:, None]
+            if window:
+                mask &= kpos[None, :] > (qpos[:, None] - window)
+            scores = jnp.where(mask[None, None], scores, NEG_INF)
+            m_new = jnp.maximum(m, scores.max(-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(scores - m_new[..., None])
+            s_new = s * alpha + p.sum(-1)
+            acc_new = acc * alpha[..., None] + jnp.einsum(
+                "bhqk,bkhd->bhqd", p.astype(q.dtype), vb
+            ).astype(jnp.float32)
+            return (m_new, s_new, acc_new), None
+
+        n = hi - lo
+        m0 = jnp.full((b, h, n), NEG_INF, jnp.float32)
+        s0 = jnp.zeros((b, h, n), jnp.float32)
+        a0 = jnp.zeros((b, h, n, dv), jnp.float32)
+        (m, s, acc), _ = lax.scan(
+            jax.checkpoint(body),
+            (m0, s0, a0),
+            (kc[first:last], vc[first:last], jnp.arange(first, last)),
+            unroll=scan_unroll(last - first),
+        )
+        return jnp.moveaxis(acc / jnp.maximum(s, 1e-30)[..., None], 1, 2)
+
+    out = [rows(lo, min(lo + chunk, sq)) for lo in range(0, sq, chunk)]
+    return jnp.concatenate(out, axis=1).astype(q.dtype)  # (B,Sq,H,dv)
 
 
 def attention_decode(

@@ -164,7 +164,7 @@ def forward(cfg: ArchConfig, params, tokens, remat: bool = True, act_specs=None,
     x = L.apply_norm(x, params["final_norm"], cfg.norm_type)
     logits = jnp.einsum("bsd,dv->bsv", x, params["unembed"])
     logits = L.constrain(logits, (act_specs or {}).get("logits"))
-    return logits, jnp.float32(0.0)
+    return logits, {"balance": jnp.float32(0.0), "load": None}
 
 
 def init_cache(cfg: ArchConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
@@ -178,7 +178,7 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
     }
 
 
-def decode_step(cfg: ArchConfig, params, cache, tokens, positions=None):
+def decode_step(cfg: ArchConfig, params, cache, tokens, positions=None, **_):
     x = params["embed"][tokens]
 
     def layer_fn(h, inp):

@@ -195,7 +195,7 @@ def forward(cfg: ArchConfig, params, tokens, remat: bool = True, act_specs=None,
     unembed = params.get("unembed", params["embed"].T)
     logits = jnp.einsum("bsd,dv->bsv", x, unembed)
     logits = L.constrain(logits, (act_specs or {}).get("logits"))
-    return logits, jnp.float32(0.0)
+    return logits, {"balance": jnp.float32(0.0), "load": None}
 
 
 # ---------------------------------------------------------------------------
@@ -219,7 +219,7 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
     }
 
 
-def decode_step(cfg: ArchConfig, params, cache, tokens, positions=None):
+def decode_step(cfg: ArchConfig, params, cache, tokens, positions=None, **_):
     period = max(1, cfg.attention_period)
     n_blocks = cfg.n_layers // period
     b = tokens.shape[0]

@@ -9,10 +9,16 @@ Layer stacks are parameterized for ``lax.scan`` (params carry a leading L
 dim); remat policy is applied by the training layer.  ``decode_step`` scans
 the layers with the stacked KV cache read in place, not carried, and writes
 the step's new rows into it once, after the scan.
+
+DeepSeek-V3-style MoE (moonshot) adds two things: multi-head latent
+attention (``cfg.kv_lora_rank``), whose cache holds the normed latent and the
+shared rotary key, and leading dense layers (``params["dense_layers"]``,
+scanned before the sparse ``params["layers"]``).
 """
 
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 
 import jax
@@ -59,34 +65,66 @@ def _mlp_params(key, cfg: ArchConfig, n_layers: int, dtype):
     }
 
 
-def _moe_params(key, cfg: ArchConfig, n_layers: int, dtype):
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
+def _mla_params(key, cfg: ArchConfig, n_layers: int, dtype):
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     ks = jax.random.split(key, 4)
     return {
-        "router": L.dense_init(ks[0], (n_layers, d, e), dtype=jnp.float32),
-        "w_gate": L.dense_init(ks[1], (n_layers, e, d, f), dtype=dtype),
-        "w_up": L.dense_init(ks[2], (n_layers, e, d, f), dtype=dtype),
-        "w_down": L.dense_init(ks[3], (n_layers, e, f, d), dtype=dtype),
+        "wq": L.dense_init(ks[0], (n_layers, d, h * (nope + rope)), dtype=dtype),
+        "wkv_a": L.dense_init(ks[1], (n_layers, d, r + rope), dtype=dtype),
+        "kv_norm": jax.tree.map(lambda a: jnp.broadcast_to(a, (n_layers,) + a.shape),
+                                L.norm_params(r, "rmsnorm")),
+        "wkv_b": L.dense_init(ks[2], (n_layers, r, h * (nope + vd)), dtype=dtype),
+        "wo": L.dense_init(ks[3], (n_layers, h * vd, d), dtype=dtype),
     }
+
+
+def _moe_params(key, cfg: ArchConfig, n_layers: int, dtype):
+    d, f, e, held = cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.held_experts
+    ks = jax.random.split(key, 7)
+    p = {
+        "router": L.dense_init(ks[0], (n_layers, d, e), dtype=jnp.float32),
+        "w_gate": L.dense_init(ks[1], (n_layers, held, d, f), dtype=dtype),
+        "w_up": L.dense_init(ks[2], (n_layers, held, d, f), dtype=dtype),
+        "w_down": L.dense_init(ks[3], (n_layers, held, f, d), dtype=dtype),
+    }
+    if cfg.shared_d_ff:
+        fs = cfg.shared_d_ff
+        p["shared_gate"] = L.dense_init(ks[4], (n_layers, d, fs), dtype=dtype)
+        p["shared_up"] = L.dense_init(ks[5], (n_layers, d, fs), dtype=dtype)
+        p["shared_down"] = L.dense_init(ks[6], (n_layers, fs, d), dtype=dtype)
+    return p
+
+
+def _layer_params(k_attn, k_mlp, cfg: ArchConfig, n_layers: int, dtype, sparse: bool):
+    attn = _mla_params if cfg.kv_lora_rank else _attn_params
+    layer = {
+        "attn_norm": _stack_norm(cfg, n_layers),
+        "mlp_norm": _stack_norm(cfg, n_layers),
+        **attn(k_attn, cfg, n_layers, dtype),
+    }
+    if sparse:
+        layer["moe"] = _moe_params(k_mlp, cfg, n_layers, dtype)
+    else:
+        d_ff = cfg.dense_d_ff if cfg.family == "moe" else cfg.d_ff
+        layer.update(_mlp_params(k_mlp, dataclasses.replace(cfg, d_ff=d_ff), n_layers,
+                                 dtype))
+    return layer
 
 
 def init_params(cfg: ArchConfig, key, dtype=jnp.bfloat16):
     keys = jax.random.split(key, 8)
     d = cfg.d_model
-    layer = {
-        "attn_norm": _stack_norm(cfg, cfg.n_layers),
-        "mlp_norm": _stack_norm(cfg, cfg.n_layers),
-        **_attn_params(keys[0], cfg, cfg.n_layers, dtype),
-    }
-    if cfg.family == "moe":
-        layer["moe"] = _moe_params(keys[1], cfg, cfg.n_layers, dtype)
-    else:
-        layer.update(_mlp_params(keys[1], cfg, cfg.n_layers, dtype))
     params = {
         "embed": L.embed_init(keys[2], (cfg.vocab, d), dtype=dtype),
-        "layers": layer,
+        "layers": _layer_params(keys[0], keys[1], cfg, cfg.n_layers - cfg.n_dense_layers,
+                                dtype, sparse=cfg.family == "moe"),
         "final_norm": L.norm_params(d, cfg.norm_type),
     }
+    if cfg.n_dense_layers:
+        params["dense_layers"] = _layer_params(
+            *jax.random.split(jax.random.fold_in(key, 1)), cfg, cfg.n_dense_layers, dtype,
+            sparse=False)
     if not cfg.tie_embeddings:
         v_out = _padded_vocab(cfg)
         params["unembed"] = L.dense_init(keys[3], (d, v_out), dtype=dtype)
@@ -172,6 +210,46 @@ def _attn_block(cfg: ArchConfig, p, x, positions, causal, window, kv_seq=None,
     return jnp.einsum("bsq,qd->bsd", o.reshape(b, s, h * hd), p["wo"])
 
 
+def _mla_q_latent(cfg: ArchConfig, p, x, positions):
+    """Latent attention's inputs: q (B, S, H, nope + rope), the normed latent
+    c_kv (B, S, r) and the rotary key k_pe (B, S, 1, rope) that all heads
+    share; rope on q's and k_pe's rotary dims."""
+    b, s, _ = x.shape
+    h, nope, rope, r = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    q = jnp.einsum("bsd,dq->bsq", x, p["wq"]).reshape(b, s, h, nope + rope)
+    with jax.named_scope(OD.LATENT):
+        kv_a = jnp.einsum("bsd,dq->bsq", x, p["wkv_a"])
+        c_kv = L.rmsnorm(kv_a[..., :r], p["kv_norm"]["scale"])
+    q_pe, k_pe = _apply_pos(cfg, q[..., nope:], kv_a[..., None, r:], positions)
+    return jnp.concatenate([q[..., :nope], q_pe], axis=-1), c_kv, k_pe
+
+
+def _mla_kv(cfg: ArchConfig, p, c_kv, k_pe):
+    """Keys (B, S, H, nope + rope) and values (B, S, H, v) of every position,
+    from the latent and the shared rotary key."""
+    b, s, _ = c_kv.shape
+    h, nope, rope = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    with jax.named_scope(OD.LATENT):
+        kv = jnp.einsum("bsr,rq->bsq", c_kv, p["wkv_b"]).reshape(b, s, h, -1)
+    k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(k_pe, (b, s, h, rope))], axis=-1)
+    return k, kv[..., nope:]
+
+
+@jax.named_scope(OD.ATTENTION)
+def _mla_block(cfg: ArchConfig, p, x, positions, causal, window, use_kernel=False):
+    """Multi-head latent attention (DeepSeek-V2/V3, no q compression): q.k
+    over nope + rope dims, scaled by 1/sqrt(nope + rope); values v wide."""
+    b, s, _ = x.shape
+    q, c_kv, k_pe = _mla_q_latent(cfg, p, x, positions)
+    k, v = _mla_kv(cfg, p, c_kv, k_pe)
+    o = L.attention(
+        q, k, v, causal=causal, window=window,
+        chunk_threshold=cfg.attn_chunk * 2, chunk=cfg.attn_chunk,
+        use_kernel=use_kernel,
+    )
+    return jnp.einsum("bsq,qd->bsd", o.reshape(b, s, -1), p["wo"])
+
+
 def _moe_ep(cfg: ArchConfig, mp, x, mesh):
     """Expert-parallel MoE: experts live on the ``model`` axis, token slabs
     move with lax.all_to_all — the paper's GPT-3-MoE traffic pattern (§V-B5).
@@ -199,6 +277,39 @@ def _mlp_block(cfg: ArchConfig, p, x):
     return L.gelu_mlp(x, p["w_up"], p["b_up"], p["w_down"], p["b_down"])
 
 
+def init_buffers(params) -> dict:
+    """The model's state that takes no gradient, made from its parameters:
+    ``router_bias``, the sparse layers' correction biases (sparse layers,
+    n_experts), zero at the start as DeepSeek-V3's are; {} without experts.
+    Training keeps the buffers beside the moments
+    (``optimizer.AdamWState.buffers``, checkpointed with them) and moves them
+    with ``update_buffers``; ``forward`` and ``decode_step`` take them."""
+    layers = params.get("layers")
+    if not isinstance(layers, dict) or "moe" not in layers:
+        return {}
+    return {"router_bias": jnp.zeros(layers["moe"]["router"].shape[::2], jnp.float32)}
+
+
+def update_buffers(cfg: ArchConfig, buffers: dict, aux: dict) -> dict:
+    """After a training step: the sigmoid routers' correction biases move by
+    the step's load (``moe.balance_bias``); other buffers stay as they are."""
+    if cfg.router != "sigmoid" or not buffers:
+        return buffers
+    return dict(buffers, router_bias=moe_lib.balance_bias(
+        buffers["router_bias"], aux["load"], cfg.bias_rate))
+
+
+def _routed_layers(cfg: ArchConfig, layers, buffers):
+    """The sparse layers' parameters with each layer's correction bias
+    beside its router, for the scan (zeros where no buffers are given)."""
+    if cfg.router != "sigmoid":
+        return layers
+    bias = (buffers or {}).get("router_bias")
+    if bias is None:
+        bias = init_buffers({"layers": layers})["router_bias"]
+    return dict(layers, moe=dict(layers["moe"], bias=bias))
+
+
 def forward(
     cfg: ArchConfig,
     params,
@@ -209,8 +320,15 @@ def forward(
     use_kernel: bool = False,
     act_specs=None,
     return_hidden: bool = False,
-) -> tuple[jax.Array, jax.Array]:
-    """Full forward pass -> (logits, moe_aux_loss).
+    buffers=None,
+) -> tuple[jax.Array, dict]:
+    """Full forward pass -> (logits, aux).
+
+    ``aux`` is ``{"balance": the balance loss, "load": (sparse layers,
+    n_experts) pairs given to each expert, or None}``: for ``moe`` the sum
+    of the sparse layers' losses, for the families without experts a 0.
+    ``buffers``: ``init_buffers``'s state (the correction biases); zeros
+    where not given.
 
     tokens: (B, S) int32 — or, for audio, decoder tokens with
     ``encoder_frames`` (B, enc_seq, D) from the (stubbed) conv frontend.
@@ -235,48 +353,71 @@ def forward(
     if cfg.enc_layers:
         assert encoder_frames is not None, "audio family needs encoder frames"
         enc_out = _encoder_forward(cfg, params["encoder"], encoder_frames, remat)
+    attn_block = _mla_block if cfg.kv_lora_rank else _attn_block
 
-    def layer_fn(carry, lp):
+    def layer_fn(sparse, carry, lp):
         h, aux = carry
-        h = h + _attn_block(cfg, lp, _norm(cfg, h, lp["attn_norm"]), positions,
-                            causal=True, window=0, use_kernel=use_kernel)
+        h = h + attn_block(cfg, lp, _norm(cfg, h, lp["attn_norm"]), positions,
+                           causal=True, window=0, use_kernel=use_kernel)
         if enc_out is not None:
             xa = _norm(cfg, h, lp["xattn_norm"])
             xp = {k[1:]: v for k, v in lp.items() if k.startswith("x") and k != "xattn_norm"}
             h = h + _attn_block(cfg, xp, xa, positions, causal=False, window=0,
                                 kv_seq=enc_out)
         m = _norm(cfg, h, lp["mlp_norm"])
-        if cfg.family == "moe":
-            with jax.named_scope(OD.MLP):
-                if cfg.moe_mode == "ep":
-                    y, a_loss = _moe_ep(cfg, lp["moe"], m, (act_specs or {}).get("mesh"))
-                elif cfg.moe_mode == "gshard":
-                    y, a_loss = moe_lib.moe_apply_gshard(
-                        m, lp["moe"], cfg.top_k, cfg.capacity_factor,
-                        expert_spec=(act_specs or {}).get("experts"))
-                else:
-                    y, a_loss = moe_lib.moe_apply(m, lp["moe"], cfg.top_k,
-                                                  cfg.capacity_factor)
-            aux = aux + a_loss
-        else:
-            y = _mlp_block(cfg, lp, m)
-        return (L.constrain(h + y, act), aux), None
+        if not sparse:
+            return (L.constrain(h + _mlp_block(cfg, lp, m), act), aux), None
+        with jax.named_scope(OD.MLP):
+            y, a_loss, load = _moe_block(cfg, lp["moe"], m, act_specs)
+        return (L.constrain(h + y, act), aux + a_loss), load
 
-    body = jax.checkpoint(layer_fn) if remat else layer_fn
+    def scan(carry, layers, sparse):
+        body = partial(layer_fn, sparse)
+        body = jax.checkpoint(body) if remat else body
+        n = jax.tree.leaves(layers)[0].shape[0]
+        return lax.scan(body, carry, layers, unroll=L.scan_unroll(n))
+
+    carry = (x, jnp.float32(0.0))
     with jax.named_scope(OD.LAYERS):
-        (x, aux), _ = lax.scan(body, (x, jnp.float32(0.0)), params["layers"],
-                               unroll=L.scan_unroll(cfg.n_layers))
+        if cfg.n_dense_layers:
+            carry, _ = scan(carry, params["dense_layers"], False)
+        (x, balance), load = scan(carry, _routed_layers(cfg, params["layers"], buffers),
+                                  cfg.family == "moe")
+
     with jax.named_scope(OD.UNEMBED):
         x = L.apply_norm(x, params["final_norm"], cfg.norm_type)
         if return_hidden:
-            return x, aux / cfg.n_layers
+            return x, _aux(cfg, balance, load)
         unembed = params.get("unembed", params["embed"].T)
         logits = jnp.einsum("bsd,dv->bsv", x, unembed)
         if logits.shape[-1] != cfg.vocab:  # TP-padded vocab: mask the tail
             keep = jnp.arange(logits.shape[-1]) < cfg.vocab
             logits = jnp.where(keep, logits, jnp.asarray(-1e30, logits.dtype))
         logits = L.constrain(logits, (act_specs or {}).get("logits"))
-    return logits, aux / cfg.n_layers
+    return logits, _aux(cfg, balance, load)
+
+
+def _aux(cfg: ArchConfig, balance, load) -> dict:
+    """``forward``'s aux: the balance loss summed over the sparse layers (for
+    the families without experts the mean over layers of nothing, 0) and
+    each expert's load per sparse layer (None without experts)."""
+    return {"balance": balance if cfg.family == "moe" else balance / cfg.n_layers,
+            "load": load}
+
+
+def _moe_block(cfg: ArchConfig, mp, m, act_specs):
+    """The expert layer of one sparse layer -> (y, balance loss, load)."""
+    if cfg.moe_mode == "tp":
+        return moe_lib.moe_block(cfg, mp, m, mp.get("bias"))
+    if cfg.router != "softmax" or cfg.shared_d_ff:
+        raise ValueError(f"moe_mode {cfg.moe_mode!r} takes the softmax router alone")
+    if cfg.moe_mode == "ep":
+        y, a_loss = _moe_ep(cfg, mp, m, (act_specs or {}).get("mesh"))
+    else:
+        y, a_loss = moe_lib.moe_apply_gshard(
+            m, mp, cfg.top_k, cfg.capacity_factor,
+            expert_spec=(act_specs or {}).get("experts"))
+    return y, a_loss, None
 
 
 def _encoder_forward(cfg: ArchConfig, enc, frames, remat):
@@ -301,6 +442,11 @@ def _encoder_forward(cfg: ArchConfig, enc, frames, remat):
 
 def init_cache(cfg: ArchConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
     hd = cfg.kq_head_dim
+    if cfg.kv_lora_rank:
+        lat = (cfg.n_layers, batch, max_len)
+        return {"c_kv": jnp.zeros(lat + (cfg.kv_lora_rank,), dtype),
+                "k_pe": jnp.zeros(lat + (cfg.qk_rope_head_dim,), dtype),
+                "len": jnp.zeros((), jnp.int32)}
     cache = {
         "k": jnp.zeros((cfg.n_layers, batch, max_len, cfg.n_kv_heads, hd), dtype),
         "v": jnp.zeros((cfg.n_layers, batch, max_len, cfg.n_kv_heads, hd), dtype),
@@ -312,7 +458,7 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
     return cache
 
 
-def decode_step(cfg: ArchConfig, params, cache, tokens, positions=None):
+def decode_step(cfg: ArchConfig, params, cache, tokens, positions=None, buffers=None):
     """One-token decode: tokens (B, 1) -> (logits (B,1,V), new_cache).
 
     The stacked cache is read inside the layer scan but never carried
@@ -325,6 +471,11 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, positions=None):
     (though with a positions-minor cache layout, which the TPU compiler picks
     for 64-wide heads, writing one position touches every tile).  Carrying the cache through the scan, or scanning over it as ``xs``, makes
     the compiler copy layers or the whole cache between layouts every step.
+
+    Latent attention caches the normed latent and the rotary key
+    (``c_kv``, ``k_pe``) and expands every position's keys and values from
+    them each step.  ``buffers`` as for ``forward``: a trained model decodes
+    with the correction biases it was trained with.
     """
     b = tokens.shape[0]
     hd = cfg.kq_head_dim
@@ -340,24 +491,37 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, positions=None):
         if cfg.rope_type == "learned":
             x = x + lax.dynamic_slice_in_dim(params["pos_embed"], pos_scalar, 1)[None]
 
-    kc, vc = cache["k"], cache["v"]
+    kn, vn = ("c_kv", "k_pe") if cfg.kv_lora_rank else ("k", "v")
+    kc, vc = cache[kn], cache[vn]
     at_len = (jnp.arange(kc.shape[2]) == pos_scalar)[None, :, None, None]
     window = cfg.local_window if cfg.family == "vlm" else 0
 
-    def layer_fn(h, lp_and_index):
+    def mla(lp, a, li):
+        q, c, k_pe = _mla_q_latent(cfg, lp, a, positions)
+        c, k_pe = c.astype(kc.dtype), k_pe[:, :, 0].astype(vc.dtype)
+        lc = jnp.where(at_len[..., 0], c, lax.dynamic_index_in_dim(kc, li, keepdims=False))
+        lk = jnp.where(at_len[..., 0], k_pe, lax.dynamic_index_in_dim(vc, li, keepdims=False))
+        k, v = _mla_kv(cfg, lp, lc, lk[:, :, None, :])
+        o = L.attention_decode(q, k, v, pos_scalar + 1, window=window)
+        return jnp.einsum("bsq,qd->bsd", o.reshape(b, 1, -1), lp["wo"]), (c, k_pe)
+
+    def layer_fn(sparse, h, lp_and_index):
         lp, li = lp_and_index
         a = _norm(cfg, h, lp["attn_norm"])
         with jax.named_scope(OD.ATTENTION):
-            q = jnp.einsum("bsd,dq->bsq", a, lp["wq"]).reshape(b, 1, h_, hd)
-            k = jnp.einsum("bsd,dq->bsq", a, lp["wk"]).reshape(b, 1, kv, hd)
-            v = jnp.einsum("bsd,dq->bsq", a, lp["wv"]).reshape(b, 1, kv, hd)
-            if cfg.rope_type in ("rope", "mrope"):
-                q, k = _apply_pos(cfg, q, k, positions)
-            k, v = k.astype(kc.dtype), v.astype(vc.dtype)
-            lk = jnp.where(at_len, k, lax.dynamic_index_in_dim(kc, li, keepdims=False))
-            lv = jnp.where(at_len, v, lax.dynamic_index_in_dim(vc, li, keepdims=False))
-            o = L.attention_decode(q, lk, lv, pos_scalar + 1, window=window)
-            attn = jnp.einsum("bsq,qd->bsd", o.reshape(b, 1, h_ * hd), lp["wo"])
+            if cfg.kv_lora_rank:
+                attn, (k, v) = mla(lp, a, li)
+            else:
+                q = jnp.einsum("bsd,dq->bsq", a, lp["wq"]).reshape(b, 1, h_, hd)
+                k = jnp.einsum("bsd,dq->bsq", a, lp["wk"]).reshape(b, 1, kv, hd)
+                v = jnp.einsum("bsd,dq->bsq", a, lp["wv"]).reshape(b, 1, kv, hd)
+                if cfg.rope_type in ("rope", "mrope"):
+                    q, k = _apply_pos(cfg, q, k, positions)
+                k, v = k.astype(kc.dtype), v.astype(vc.dtype)
+                lk = jnp.where(at_len, k, lax.dynamic_index_in_dim(kc, li, keepdims=False))
+                lv = jnp.where(at_len, v, lax.dynamic_index_in_dim(vc, li, keepdims=False))
+                o = L.attention_decode(q, lk, lv, pos_scalar + 1, window=window)
+                attn = jnp.einsum("bsq,qd->bsd", o.reshape(b, 1, h_ * hd), lp["wo"])
         h = h + attn
         if cfg.enc_layers:
             xa = _norm(cfg, h, lp["xattn_norm"])
@@ -369,22 +533,32 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, positions=None):
                 attn = jnp.einsum("bsq,qd->bsd", o.reshape(b, 1, h_ * hd), lp["xwo"])
             h = h + attn
         m = _norm(cfg, h, lp["mlp_norm"])
-        if cfg.family == "moe":
+        if sparse:
             with jax.named_scope(OD.MLP):
-                y, _ = moe_lib.moe_apply(m, lp["moe"], cfg.top_k, cfg.capacity_factor)
+                y, _, _ = moe_lib.moe_block(cfg, lp["moe"], m, lp["moe"].get("bias"))
         else:
             y = _mlp_block(cfg, lp, m)
         return h + y, (k, v)
 
+    n_dense = cfg.n_dense_layers
     with jax.named_scope(OD.LAYERS):
+        if n_dense:
+            x, dense_rows = lax.scan(partial(layer_fn, False), x,
+                                     (params["dense_layers"], jnp.arange(n_dense)))
         x, (k_rows, v_rows) = lax.scan(
-            layer_fn, x, (params["layers"], jnp.arange(cfg.n_layers)))
+            partial(layer_fn, cfg.family == "moe"), x,
+            (_routed_layers(cfg, params["layers"], buffers),
+             jnp.arange(n_dense, cfg.n_layers)))
+        if n_dense:
+            k_rows = jnp.concatenate([dense_rows[0], k_rows])
+            v_rows = jnp.concatenate([dense_rows[1], v_rows])
         with jax.named_scope(OD.ATTENTION):
-            kc = lax.dynamic_update_slice(kc, k_rows, (0, 0, pos_scalar, 0, 0))
-            vc = lax.dynamic_update_slice(vc, v_rows, (0, 0, pos_scalar, 0, 0))
+            at = (0, 0, pos_scalar) + (0,) * (kc.ndim - 3)
+            kc = lax.dynamic_update_slice(kc, k_rows, at)
+            vc = lax.dynamic_update_slice(vc, v_rows, at)
     with jax.named_scope(OD.UNEMBED):
         x = L.apply_norm(x, params["final_norm"], cfg.norm_type)
         unembed = params.get("unembed", params["embed"].T)
         logits = jnp.einsum("bsd,dv->bsv", x, unembed)
-    new_cache = dict(cache, k=kc, v=vc, len=pos_scalar + 1)
+    new_cache = dict(cache, **{kn: kc, vn: vc}, len=pos_scalar + 1)
     return logits, new_cache

@@ -53,6 +53,15 @@ LOSS = "loss"
 GRAD_SYNC = "grad_sync"
 OPTIMIZER = "optimizer"
 SCOPES = (EMBED, ATTENTION, MLP, NORM, UNEMBED, LAYERS, LOSS, GRAD_SYNC, OPTIMIZER)
+# named scopes nested in those above, not in SCOPES: a reader takes them by
+# name (chipbench's ``Reading.per_step_ms_under``)
+LATENT = "latent"  # under attention: latent attention's W_kv_a, its norm and W_kv_b
+MOE = "moe"  # under mlp: the whole expert layer (the scopes below, the loop over choices)
+ROUTER = "router"  # under moe: router logits, scores, top-k choice, gates, balance loss
+DISPATCH = "dispatch"  # under moe: (token, choice) pairs sorted by held expert, rows gathered
+EXPERTS = "experts"  # under moe: the held experts' grouped matmuls
+SHARED_EXPERTS = "shared_experts"  # under moe: the MLP every token passes through
+COMBINE = "combine"  # under moe: expert rows back to their tokens, weighted by the gates
 
 _calls = itertools.count()
 _profiler = None

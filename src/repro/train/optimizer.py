@@ -13,11 +13,17 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.models import transformer
+
 
 class AdamWState(NamedTuple):
     step: jax.Array
     m: dict
     v: dict
+    # the model's state without gradient (``transformer.init_buffers``: the
+    # routers' correction biases), kept beside the moments and checkpointed
+    # with them; no gradient, no decay: the train step moves it
+    buffers: dict
 
 
 @dataclasses.dataclass(frozen=True)
@@ -52,9 +58,11 @@ def schedule_lr(cfg: AdamWConfig, step: jax.Array) -> jax.Array:
 
 
 def init(params) -> AdamWState:
+    """Zero moments in the parameters' tree, and the model's own buffers."""
     zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
     return AdamWState(step=jnp.zeros((), jnp.int32), m=zeros,
-                      v=jax.tree.map(jnp.copy, zeros))
+                      v=jax.tree.map(jnp.copy, zeros),
+                      buffers=transformer.init_buffers(params))
 
 
 def global_norm(tree) -> jax.Array:
@@ -93,4 +101,4 @@ def apply(cfg: AdamWConfig, state: AdamWState, params, grads):
     new_p = jax.tree.unflatten(treedef, [o[0] for o in out])
     new_m = jax.tree.unflatten(treedef, [o[1] for o in out])
     new_v = jax.tree.unflatten(treedef, [o[2] for o in out])
-    return new_p, AdamWState(step, new_m, new_v), {"lr": lr, "grad_norm": gnorm}
+    return new_p, AdamWState(step, new_m, new_v, state.buffers), {"lr": lr, "grad_norm": gnorm}

@@ -25,7 +25,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.core import collectives as coll
-from repro.models import get_model
+from repro.models import get_model, transformer
 from repro.obs import device as OD
 from repro.parallel.sharding import Policy
 from repro.train import optimizer as opt
@@ -37,7 +37,6 @@ class TrainOptions:
     remat: bool = True
     use_kernel: bool = False
     compress_k: int = 0
-    moe_aux_weight: float = 0.01
     # sequence-chunked CE: compute unembed+loss in S-chunks so the full
     # (tokens, vocab) logits are never materialized (0 = off).
     ce_chunk: int = 0
@@ -57,10 +56,13 @@ def cross_entropy(logits: jax.Array, labels: jax.Array) -> jax.Array:
 
 
 def make_loss_fn(cfg: ArchConfig, options: TrainOptions, act_specs=None):
+    """loss_fn(params, batch, buffers) -> (loss + the weighted balance loss,
+    (loss, aux)); ``buffers`` is the model's state without gradient (the
+    optimizer state's ``buffers``)."""
     model = get_model(cfg)
 
-    def loss_fn(params, batch):
-        extras = {}
+    def loss_fn(params, batch, buffers):
+        extras = {"buffers": buffers}
         if "positions" in batch:
             extras["positions"] = batch["positions"]
         if "encoder_frames" in batch:
@@ -82,9 +84,21 @@ def make_loss_fn(cfg: ArchConfig, options: TrainOptions, act_specs=None):
             )
             with jax.named_scope(OD.LOSS):
                 loss = cross_entropy(logits, batch["labels"])
-        return loss + options.moe_aux_weight * aux, (loss, aux)
+        return loss + cfg.moe_aux_weight * aux["balance"], (loss, aux)
 
     return loss_fn
+
+
+def _step_out(cfg: ArchConfig, state: opt.AdamWState, loss, aux, m):
+    """The step's new optimizer state and metrics: the model's buffers move
+    by the step (the correction biases by each expert's load), and where the
+    model routes, the metrics carry that load per sparse layer."""
+    with jax.named_scope(OD.OPTIMIZER):
+        state = state._replace(buffers=transformer.update_buffers(cfg, state.buffers, aux))
+    metrics = {"loss": loss, "aux": aux["balance"], **m}
+    if aux["load"] is not None:
+        metrics["load"] = aux["load"]
+    return state, metrics
 
 
 def chunked_cross_entropy(hidden, unembed, labels, vocab: int, chunk: int):
@@ -133,11 +147,12 @@ def make_train_step(cfg: ArchConfig, ocfg: opt.AdamWConfig, options: TrainOption
 
         def train_step(params, opt_state, batch):
             (tot, (loss, aux)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                params, batch
+                params, batch, opt_state.buffers
             )
             with jax.named_scope(OD.OPTIMIZER):
                 new_params, new_state, m = opt.apply(ocfg, opt_state, params, grads)
-            return new_params, new_state, {"loss": loss, "aux": aux, **m}
+            new_state, metrics = _step_out(cfg, new_state, loss, aux, m)
+            return new_params, new_state, metrics
 
         return train_step
 
@@ -168,10 +183,10 @@ def make_train_step(cfg: ArchConfig, ocfg: opt.AdamWConfig, options: TrainOption
         inner_act_specs = {k: strip(v) for k, v in act_specs.items()}
         loss_fn = make_loss_fn(cfg, options, act_specs=inner_act_specs)
 
-    def synced_grads(params, batch):
+    def synced_grads(params, batch, buffers):
         """Runs on one data shard (manual); model axis stays auto."""
         (tot, (loss, aux)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params, batch
+            params, batch, buffers
         )
         axes = data_axes if len(data_axes) > 1 else (data_axes[0],)
         with jax.named_scope(OD.GRAD_SYNC):
@@ -191,7 +206,9 @@ def make_train_step(cfg: ArchConfig, ocfg: opt.AdamWConfig, options: TrainOption
             else:
                 grads = coll.allreduce_tree(grads, algo, axes, dp_shape, mean=True)
             loss = jax.lax.pmean(loss, axes)
-            aux = jax.lax.pmean(aux, axes)
+            load = aux["load"]
+            aux = {"balance": jax.lax.pmean(aux["balance"], axes),
+                   "load": None if load is None else jax.lax.psum(load, axes)}
         return grads, loss, aux
 
     def dp_total(axes):
@@ -204,15 +221,16 @@ def make_train_step(cfg: ArchConfig, ocfg: opt.AdamWConfig, options: TrainOption
         grads_fn = jax.shard_map(
             synced_grads,
             mesh=mesh,
-            in_specs=(P(), jax.tree.map(lambda _: P(policy.dp), batch)),
+            in_specs=(P(), jax.tree.map(lambda _: P(policy.dp), batch), P()),
             out_specs=(P(), P(), P()),
             axis_names=set(data_axes),
             check_vma=False,
         )
-        grads, loss, aux = grads_fn(params, batch)
+        grads, loss, aux = grads_fn(params, batch, opt_state.buffers)
         with jax.named_scope(OD.OPTIMIZER):
             new_params, new_state, m = opt.apply(ocfg, opt_state, params, grads)
-        return new_params, new_state, {"loss": loss, "aux": aux, **m}
+        new_state, metrics = _step_out(cfg, new_state, loss, aux, m)
+        return new_params, new_state, metrics
 
     return train_step
 
@@ -225,8 +243,8 @@ def make_train_step(cfg: ArchConfig, ocfg: opt.AdamWConfig, options: TrainOption
 def make_prefill_step(cfg: ArchConfig, options: TrainOptions, act_specs=None):
     model = get_model(cfg)
 
-    def prefill_step(params, batch):
-        extras = {}
+    def prefill_step(params, batch, buffers=None):
+        extras = {"buffers": buffers}
         if "positions" in batch:
             extras["positions"] = batch["positions"]
         if "encoder_frames" in batch:
@@ -243,8 +261,8 @@ def make_prefill_step(cfg: ArchConfig, options: TrainOptions, act_specs=None):
 def make_decode_step(cfg: ArchConfig):
     model = get_model(cfg)
 
-    def serve_step(params, cache, tokens):
-        logits, new_cache = model.decode_step(cfg, params, cache, tokens)
+    def serve_step(params, cache, tokens, buffers=None):
+        logits, new_cache = model.decode_step(cfg, params, cache, tokens, buffers=buffers)
         next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         return next_tok[:, None], new_cache
 

@@ -277,6 +277,7 @@ def check_pipeline_parallel():
 
 def check_moe_ep():
     """Expert-parallel MoE (all_to_all) == single-device dispatch."""
+    from repro.configs.base import ArchConfig
     from repro.models import moe as moe_lib
 
     mesh = make_mesh((4,), ("model",))
@@ -292,8 +293,9 @@ def check_moe_ep():
     }
     params["w_down"] = jax.random.normal(jax.random.PRNGKey(9), (e, f, d)) * 0.1
 
-    # reference: single-group dense dispatch with ample capacity
-    y_ref, _ = moe_lib.moe_apply(x, params, k, capacity_factor=float(e))
+    # reference: the dropless layer over all experts (softmax router)
+    cfg = ArchConfig("moe", "moe", 1, d, 1, 1, f, 8, n_experts=e, top_k=k)
+    y_ref, _, _ = moe_lib.moe_block(cfg, params, x)
 
     def ep(xx, pp):
         local = jax.tree.map(lambda v: v, pp)

@@ -147,3 +147,12 @@ def test_chunked_attention_matches_dense():
             np.asarray(dense, np.float32), np.asarray(chunked, np.float32),
             rtol=2e-5, atol=2e-5,
         )
+    # a length that is no multiple of the chunk, and values narrower than keys
+    q, k, v = q[:, :50], k[:, :50], v[:, :50, :, :8]
+    for causal, window in [(True, 0), (True, 20), (False, 0)]:
+        dense = L.attention_dense(q, k, v, causal=causal, window=window)
+        chunked = L.attention_chunked(q, k, v, causal=causal, window=window, chunk=16)
+        np.testing.assert_allclose(
+            np.asarray(dense, np.float32), np.asarray(chunked, np.float32),
+            rtol=2e-5, atol=2e-5,
+        )

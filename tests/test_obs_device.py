@@ -51,6 +51,28 @@ def test_train_step_hlo_carries_every_scope():
     assert found == MODEL_SCOPES | {OD.LOSS, OD.OPTIMIZER}
 
 
+def test_moe_train_step_hlo_carries_the_nested_scopes():
+    """Latent attention and the expert layer name their parts under
+    ``attention`` and ``mlp``; the whole expert layer, with its loop over
+    each token's choices and that loop's gradient sums, is under ``moe``."""
+    cfg = get_config("moonshot-v1-16b-a3b-smoke")
+    mesh, params, opt_state, step = T.build(cfg, steps=10)
+    b = T.place_batch({"tokens": jnp.zeros((2, 16), jnp.int32),
+                       "labels": jnp.zeros((2, 16), jnp.int32)}, mesh)
+    text = step.lower(params, opt_state, b).compile().as_text()
+    nested = {OD.LATENT: OD.ATTENTION, OD.MOE: OD.MLP, OD.ROUTER: OD.MOE,
+              OD.DISPATCH: OD.MOE, OD.EXPERTS: OD.MOE, OD.SHARED_EXPERTS: OD.MOE,
+              OD.COMBINE: OD.MOE}
+    ops = re.findall(r'op_name="([^"]*)"', text)
+    for name, outer in nested.items():
+        under = re.compile(rf"(^|/){outer}/(.*/)?{name}/")
+        assert any(under.search(op) for op in ops), name
+    loop = re.compile(rf"(^|/){OD.MLP}/(.*/)?while/")
+    assert not [op for op in ops if loop.search(op) and f"/{OD.MOE}/" not in op]
+    assert any(loop.search(op) and op.endswith("add_any") for op in ops)
+    assert scopes_in(text) == MODEL_SCOPES | {OD.LOSS, OD.OPTIMIZER}
+
+
 def test_decode_step_hlo_carries_the_model_scopes():
     cfg = get_config(ARCH)
     model = get_model(cfg)

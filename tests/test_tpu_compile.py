@@ -77,6 +77,28 @@ def test_flash_kernel_compiles(one_chip, layout):
     assert "tpu_custom_call" in fn.lower(q, k, k).compile().as_text()
 
 
+def test_grouped_matmul_kernel_compiles(one_chip):
+    """The held experts' grouped matmul at Moonlight-16B-A3B's widths: 8192
+    rows of float32 pairs, 8 held experts of 2048 x 1408 (and back), one
+    last group for the pairs held elsewhere; its backward too."""
+    from repro.kernels.ops import megablox
+    from repro.models import moe as moe_lib
+
+    x = jax.ShapeDtypeStruct((8192, 2048), jnp.float32, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((8, 2048, 1408), jnp.float32, sharding=one_chip)
+    w_down = jax.ShapeDtypeStruct((8, 1408, 2048), jnp.float32, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((9,), jnp.int32, sharding=one_chip)
+
+    def gmm(a, b, sizes):  # compiled, not interpreted: the process's backend is the CPU
+        return megablox.gmm(a, b, sizes, a.dtype, moe_lib._tiling, None, None, False, False)
+
+    def expert(x, w, w_down, sizes):
+        return jnp.sum(gmm(jax.nn.silu(gmm(x, w, sizes)), w_down, sizes))
+
+    fn = jax.jit(jax.grad(expert, argnums=(0, 1, 2)))
+    assert "tpu_custom_call" in fn.lower(x, w, w_down, sizes).compile().as_text()
+
+
 def test_rmsnorm_kernel_compiles(one_chip):
     x = jax.ShapeDtypeStruct((2, 2048, MINICPM.d_model), jnp.bfloat16,
                              sharding=one_chip)
